@@ -32,6 +32,8 @@ def main(argv=None) -> None:
                     help="reduced CI sweep: "
                          f"{SMOKE_BENCHES} with shrunk configs")
     args = ap.parse_args(argv)
+    from repro.runtime.compile_cache import configure_compile_cache
+    configure_compile_cache()
     if args.smoke:
         from benchmarks import common
         common.set_smoke(True)

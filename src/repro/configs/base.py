@@ -59,11 +59,11 @@ class PruneConfig:
     #     scoring, block-local selection, winner gather, and exact
     #     attention in one kernel/XLA region instead of the composed
     #     three-pass flow. The composed path stays as the oracle.
-    #     fused="auto" picks the measured-faster engine per backend: the
-    #     Pallas kernel on TPU (where its winner-only DMA gather pays),
-    #     the composed path elsewhere (the XLA fallback was measured at
-    #     parity-to-slower off-TPU — see core/attention.fused_auto_decision,
-    #     which benches record into BENCH_latency.json). ---
+    #     fused="auto" picks the engine per backend: the Pallas kernels on
+    #     TPU (scores and winners stay in VMEM; their speed on the chip is
+    #     not yet measured), the composed path elsewhere (the XLA fallback
+    #     was measured at parity-to-slower on CPU — see
+    #     core/attention.fused_auto_decision). ---
     fused: object = False        # False | True | "auto"
     fused_backend: str = "auto"  # 'auto' | 'pallas' | 'xla'
     # --- charge-domain accumulation ---

@@ -150,7 +150,7 @@ def _blocked_attend_shardmap(cache: KVCache, q: jax.Array,
     """
     from jax.sharding import PartitionSpec as P
 
-    from repro.runtime.compat import shard_map
+    from jax import shard_map
 
     b, hq, d = q.shape
     hk = cache.k.shape[1]
@@ -210,25 +210,26 @@ def _blocked_attend_shardmap(cache: KVCache, q: jax.Array,
 
 
 def fused_auto_decision() -> dict:
-    """How `PruneConfig(fused="auto")` resolves on this backend, with the
-    measured rationale (benches record this into BENCH_latency.json).
+    """How `PruneConfig(fused="auto")` resolves on this backend, and why
+    (benches record this into BENCH_latency.json).
 
-    The fused engine's advantage is the Pallas kernel's winner-only DMA
-    gather — the unselected K/V rows never leave HBM. Off-TPU the kernel
-    lowers to the XLA fallback (`ref.fused_decode_ref`), whose gather
-    offers no such bandwidth win: interleaved min-time profiling at
-    ctx512 put it at parity-to-~6%-slower than the composed three-pass
-    path (identical FLOPs/bytes per XLA cost analysis; the historical
-    1.3x figure was sequential-median timing noise). auto therefore runs
-    fused only where the kernel is real."""
+    On TPU auto picks the fused engine: the Pallas kernels keep the
+    [B,Hq,S] scores and the index tensors of the composed path in VMEM
+    and never write them to HBM. That this makes decode faster on the
+    chip is a premise — no chip benchmark has timed the two engines yet
+    (ROADMAP Queue 1). Off-TPU the kernel would lower to the XLA fallback
+    (`ref.fused_decode_ref`), which CPU profiling at ctx512 put at
+    parity-to-~6%-slower than the composed path, so auto runs the
+    composed path there."""
     on_tpu = jax.default_backend() == "tpu"
     return {
         "engine": "fused" if on_tpu else "composed",
         "backend": jax.default_backend(),
-        "reason": ("pallas kernel: winner-only DMA gather pays on TPU"
+        "reason": ("pallas kernels: scores and winners stay in VMEM "
+                   "(speed on the chip not yet measured)"
                    if on_tpu else
                    "xla fallback measured at parity-to-slower vs the "
-                   "composed path off-TPU (no DMA-gather advantage)"),
+                   "composed path off-TPU"),
     }
 
 
@@ -238,32 +239,38 @@ def _fused_enabled(prune: PruneConfig) -> bool:
     return bool(prune.fused)
 
 
-def _fused_eligible(cache: KVCache, prune: PruneConfig) -> bool:
-    """The fused engine covers the paper-default decode configuration;
+def decode_engine(prune: PruneConfig, latent_cache: bool = False) -> str:
+    """The unicaim decode engine `prune` resolves to outside a mesh
+    context: "fused" or "composed" (other policies: the policy name).
+    The fused engine covers the paper-default decode configuration;
     anything it doesn't (threshold race, exact accumulation, MLA latent
-    caches, slot-sharded meshes) falls back to the composed oracle path."""
-    if not (_fused_enabled(prune) and prune.policy == "unicaim"):
-        return False
-    if prune.select_mode != "topk" or prune.accumulate != "approx":
-        return False
-    if cache.v is None:                       # MLA latent cache
-        return False
-    nb = max(1, prune.select_blocks)
-    if prune.select_k % nb:
-        return False
+    caches) runs the composed oracle path."""
+    if prune.policy != "unicaim":
+        return prune.policy
+    if (_fused_enabled(prune) and prune.select_mode == "topk"
+            and prune.accumulate == "approx" and not latent_cache
+            and prune.select_k % max(1, prune.select_blocks) == 0):
+        return "fused"
+    return "composed"
+
+
+def _fused_eligible(cache: KVCache, prune: PruneConfig) -> bool:
     from repro.runtime.sharding import active_mesh
-    # under any mesh the composed path owns distribution (shard constraint
-    # re-pinning / the shard_map race); the fused kernel is unsharded and
-    # would force GSPMD to all-gather the cache around the pallas_call
-    return active_mesh() is None
+    # under a mesh context the composed path owns distribution (shard
+    # constraint re-pinning / the shard_map race); the fused kernel is
+    # unsharded and would force GSPMD to all-gather the cache around the
+    # pallas_call. (ServeLoop(mesh=...) sets no such context: its
+    # shard_map body runs the kernel per shard.)
+    return (decode_engine(prune, latent_cache=cache.v is None) == "fused"
+            and active_mesh() is None)
 
 
 def _fused_decode_attend(cache: KVCache, q: jax.Array, prune: PruneConfig
                          ) -> Tuple[KVCache, jax.Array]:
     """Single-pass fused engine: one kernel (or one fused XLA region) does
-    CAM scoring over the mirror, block-local selection, winner-only
-    gather, exact attention, AND emits the charge-domain accumulation
-    probabilities — no [B,Hq,S] scores or index tensors between passes."""
+    CAM scoring over the mirror, top-k selection, exact attention over
+    the winners, AND emits the charge-domain accumulation probabilities
+    — no [B,Hq,S] scores or index tensors between passes."""
     from repro.kernels import ops
 
     b, hq, d = q.shape

@@ -5,19 +5,22 @@ decode pipeline that `core/attention.py` otherwise composes from three
 passes (approx_score → top-k → gather_attention):
 
   1. CAM mode     — int8 scores for the block's slots from the quantized
-                    key mirror (the mirror's ONLY HBM read),
+                    key mirror,
   2. CAM race     — block-local top-k selection entirely in VMEM
-                    (iterative argmax; protected slots always win),
-  3. current mode — per-winner DMA of the K/V rows from HBM and the exact
-                    online-softmax attention contraction across blocks,
+                    (iterative max race; protected slots always win),
+  3. current mode — exact online-softmax attention over the block's
+                    winners, accumulated across blocks,
   4. charge mode  — per-slot approximate probabilities (the accumulated-
                     score update) emitted from the score scratch at the
                     last block.
 
 Nothing round-trips HBM between the stages: the [B,Hq,S] score tensor and
-[B,Hk,nb,k] index tensor of the composed path never materialize, and the
-unselected bf16/int8 K/V rows are never touched — K and V live in ANY
-(HBM) memory space and only the k_loc winners per block are DMA'd.
+[B,Hk,nb,k] index tensor of the composed path never materialize. The
+race yields a winner MASK, not an index list, so the current-mode pass is
+a masked attention over the K/V block the pipeline already streamed into
+VMEM. A per-row winner DMA is not an option on the TPU: the HBM layout
+tiles K/V rows (16 bf16 rows per tile, d=64 padded to 128 lanes), and
+Mosaic refuses a one-row slice of a tile.
 
 Selection semantics match the composed path: with num_blocks == 1 this is
 the global `exact_topk`; with num_blocks == nb it is the hierarchical
@@ -32,10 +35,14 @@ per-block race of `select_blocks = nb` (`_gathered_attend_blocked`).
   vscale [BH, S]      f32             V-row dequant scale (ones for bf16)
   valid  [BH, S]      int8
   prot   [BH, S]      int8            protected (sinks + recent): race bias
-  k      [BH, S, d]   ANY/HBM        exact keys   — winners DMA'd only
-  v      [BH, S, dv]  ANY/HBM        exact values — winners DMA'd only
+  k      [BH, S, d]   storage dtype   exact keys
+  v      [BH, S, dv]  storage dtype   exact values
   out    [BH, G, dv]  f32
   probs  [BH, S]      f32            Σ_g softmax_g(scores/√d) — acc update
+
+Inside the kernel every per-slot vector is a lane row ([1, S] or [8, bs]
+blocks of `meta`) and every per-query scalar a column ([G, 1]), so no
+value ever changes between the lane and sublane axes.
 """
 from __future__ import annotations
 
@@ -53,104 +60,120 @@ NEG_INF = -1e30
 PROT_WIN = 1e30
 PICKED = -1e35
 
+# Rows of the packed per-slot metadata operand ([BH, META_ROWS, S] f32).
+MS, KS, VS, VALID, PROT = range(5)
+META_ROWS = 8                  # one f32 sublane tile
+_HI = jax.lax.Precision.HIGHEST
 
-def _fused_decode_kernel(q_ref, qq_ref, qs_ref, mir_ref, ms_ref, ks_ref,
-                         vs_ref, valid_ref, prot_ref, k_any, v_any,
-                         out_ref, probs_ref,
-                         score_buf, m_sc, l_sc, o_sc, ksel, vsel, sem,
+
+def pack_meta(mscale, kscale, vscale, valid, prot, s_pad: int) -> jax.Array:
+    """[BH, S] per-slot vectors → one [BH, 8, s_pad] f32 operand (rows MS,
+    KS, VS, VALID, PROT, zeros). Slots past S are invalid and unprotected."""
+    rows = [x.astype(jnp.float32) for x in (mscale, kscale, vscale, valid,
+                                            prot)]
+    meta = jnp.stack(rows, axis=1)
+    bh, r, s = meta.shape
+    return jnp.pad(meta, ((0, 0), (0, META_ROWS - r), (0, s_pad - s)))
+
+
+def cam_scores(qq, qs, mir, meta):
+    """CAM mode for one slot block: [G, d] int8 · [bs, d] int8 → masked
+    scores [G, bs]. The integer operands are exact in bf16 and their
+    products sum exactly in f32, so this equals the oracle's f32 einsum."""
+    raw = jax.lax.dot_general(
+        qq.astype(jnp.bfloat16), mir.astype(jnp.bfloat16),
+        dimension_numbers=(((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32)                # [G, bs]
+    raw = raw * qs * meta[MS:MS + 1]
+    return jnp.where(meta[VALID:VALID + 1] != 0, raw, NEG_INF)
+
+
+def race(ssel, n_pick: int):
+    """CAM race: mask of the `n_pick` largest entries of ssel [1, n], ties
+    to the lower slot (lax.top_k's order). Each round takes the row max,
+    then the first lane holding it — two lane reductions, no scalar."""
+    n = ssel.shape[-1]
+    iota = jax.lax.broadcasted_iota(jnp.int32, ssel.shape, 1)
+
+    def pick(_, sc):
+        m = jnp.max(sc, axis=-1, keepdims=True)
+        idx = jnp.min(jnp.where(sc == m, iota, n), axis=-1, keepdims=True)
+        return jnp.where(iota == idx, PICKED, sc)
+
+    return jax.lax.fori_loop(0, n_pick, pick, ssel) == PICKED
+
+
+def attend_block(q, k, v, meta, sel, scale, m_sc, l_sc, o_sc, n_valid=None):
+    """Current mode for one slot block: online-softmax attention of q
+    [G, d] over the block's winners (`sel` [1, bs] & valid), folding the
+    per-slot K/V dequant scales into the logits and the probabilities.
+    `n_valid` (static) marks rows past the array end in a partial last
+    block: their V rows are zeroed so 0·garbage cannot poison p @ V."""
+    kf = k.astype(jnp.float32)
+    vf = v.astype(jnp.float32)
+    if n_valid is not None:
+        rows = jax.lax.broadcasted_iota(jnp.int32, vf.shape, 0)
+        vf = jnp.where(rows < n_valid, vf, 0.0)
+    logits = jax.lax.dot_general(
+        q.astype(jnp.float32), kf,
+        dimension_numbers=(((1,), (1,)), ((), ())), precision=_HI,
+        preferred_element_type=jnp.float32)                # [G, bs]
+    logits = logits * meta[KS:KS + 1] * scale
+    live = sel & (meta[VALID:VALID + 1] != 0)
+    logits = jnp.where(live, logits, NEG_INF)
+    m_prev = m_sc[...]
+    m_new = jnp.maximum(m_prev, jnp.max(logits, axis=-1, keepdims=True))
+    corr = jnp.exp(m_prev - m_new)
+    p = jnp.where(logits > NEG_INF / 2, jnp.exp(logits - m_new), 0.0)
+    l_sc[...] = l_sc[...] * corr + jnp.sum(p, axis=-1, keepdims=True)
+    o_sc[...] = o_sc[...] * corr + jax.lax.dot(
+        p * meta[VS:VS + 1], vf, precision=_HI,
+        preferred_element_type=jnp.float32)
+    m_sc[...] = m_new
+
+
+def charge_probs(buf, scale):
+    """Charge mode: Σ_g softmax_g(buf · scale) over the slots → [1, S]."""
+    lg = buf * scale
+    mg = jnp.max(lg, axis=-1, keepdims=True)
+    e = jnp.where(buf > NEG_INF / 2, jnp.exp(lg - mg), 0.0)
+    z = jnp.sum(e, axis=-1, keepdims=True)
+    return jnp.sum(e / jnp.maximum(z, 1e-30), axis=0, keepdims=True)
+
+
+def init_softmax(m_sc, l_sc, o_sc):
+    m_sc[...] = jnp.full_like(m_sc, NEG_INF)
+    l_sc[...] = jnp.zeros_like(l_sc)
+    o_sc[...] = jnp.zeros_like(o_sc)
+
+
+def lane_offset(j, bs: int):
+    """Start of slot block j in a lane row, with its alignment stated."""
+    return pl.multiple_of(j * bs, bs)
+
+
+def _fused_decode_kernel(q_ref, qq_ref, qs_ref, mir_ref, meta_ref, k_ref,
+                         v_ref, out_ref, probs_ref,
+                         score_buf, m_sc, l_sc, o_sc,
                          *, nb, bs, k_loc, scale):
-    i = pl.program_id(0)
     j = pl.program_id(1)
 
     @pl.when(j == 0)
     def _init():
-        m_sc[...] = jnp.full_like(m_sc, NEG_INF)
-        l_sc[...] = jnp.zeros_like(l_sc)
-        o_sc[...] = jnp.zeros_like(o_sc)
+        init_softmax(m_sc, l_sc, o_sc)
 
-    # -- CAM mode: score this slot block against the whole query group --
-    qqf = qq_ref[0].astype(jnp.float32)                    # [G, d]
-    mir = mir_ref[0].astype(jnp.float32)                   # [bs, d]
-    raw = jax.lax.dot_general(
-        qqf, mir, dimension_numbers=(((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32)                # [G, bs]
-    raw = raw * qs_ref[0][:, None] * ms_ref[0][None, :]
-    validb = valid_ref[0][None, :] != 0                    # [1, bs]
-    raw = jnp.where(validb, raw, NEG_INF)
-    score_buf[:, pl.ds(j * bs, bs)] = raw
-
-    # -- CAM race: block-local top-k on the group-summed biased scores --
+    meta = meta_ref[0]                                     # [8, bs]
+    raw = cam_scores(qq_ref[0], qs_ref[0], mir_ref[0], meta)
+    score_buf[:, pl.ds(lane_offset(j, bs), bs)] = raw
     ssel = jnp.sum(raw, axis=0, keepdims=True)             # [1, bs]
-    ssel = jnp.where(prot_ref[0][None, :] != 0, PROT_WIN, ssel)
-    iota_s = jax.lax.broadcasted_iota(jnp.int32, (1, bs), 1)
-    iota_k = jax.lax.broadcasted_iota(jnp.int32, (k_loc, 1), 0)
-    base = j * bs
+    ssel = jnp.where(meta[PROT:PROT + 1] != 0, PROT_WIN, ssel)
+    attend_block(q_ref[0], k_ref[0], v_ref[0], meta, race(ssel, k_loc),
+                 scale, m_sc, l_sc, o_sc)
 
-    def _copies(slot_idx, t):
-        """DMA descriptors for winner `slot_idx` → gather row `t`."""
-        return (pltpu.make_async_copy(k_any.at[i, pl.ds(base + slot_idx, 1)],
-                                      ksel.at[pl.ds(t, 1)], sem.at[0]),
-                pltpu.make_async_copy(v_any.at[i, pl.ds(base + slot_idx, 1)],
-                                      vsel.at[pl.ds(t, 1)], sem.at[1]))
-
-    def select_one(t, carry):
-        sc, onehot, prev = carry
-        idx = jnp.argmax(sc).astype(jnp.int32)             # first max wins
-        row = iota_s == idx                                # [1, bs]
-        onehot = onehot + jnp.where((iota_k == t) & row, 1.0, 0.0)
-        # depth-1 DMA pipeline: winner t-1's rows fly while t is argmax'd;
-        # drain them before reusing the semaphore pair for winner t
-        @pl.when(t > 0)
-        def _drain_prev():
-            for cp in _copies(prev, t - 1):
-                cp.wait()
-
-        for cp in _copies(idx, t):
-            cp.start()
-        return jnp.where(row, PICKED, sc), onehot, idx
-
-    carry0 = (ssel, jnp.zeros((k_loc, bs), jnp.float32), jnp.int32(0))
-    _, onehot, last = jax.lax.fori_loop(0, k_loc, select_one, carry0)
-    for cp in _copies(last, k_loc - 1):                    # final winner
-        cp.wait()
-
-    # winner metadata rides the one-hot matmul (bytes ≪ the skipped rows)
-    sel_ks = jax.lax.dot(onehot, ks_ref[0][:, None],
-                         preferred_element_type=jnp.float32)   # [k_loc, 1]
-    sel_vs = jax.lax.dot(onehot, vs_ref[0][:, None],
-                         preferred_element_type=jnp.float32)
-    sel_valid = jax.lax.dot(
-        onehot, (validb[0][:, None]).astype(jnp.float32),
-        preferred_element_type=jnp.float32)                    # [k_loc, 1]
-
-    # -- current-domain mode: exact online-softmax attention over winners --
-    k_rows = ksel[...].astype(jnp.float32) * sel_ks            # [k_loc, d]
-    v_rows = vsel[...].astype(jnp.float32) * sel_vs            # [k_loc, dv]
-    qf = q_ref[0].astype(jnp.float32)                          # [G, d]
-    logits = jax.lax.dot_general(
-        qf, k_rows, dimension_numbers=(((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32) * scale            # [G, k_loc]
-    logits = jnp.where(sel_valid[:, 0][None, :] > 0.5, logits, NEG_INF)
-    m_prev = m_sc[...]
-    m_new = jnp.maximum(m_prev, jnp.max(logits, axis=-1, keepdims=True))
-    corr = jnp.exp(m_prev - m_new)
-    p = jnp.exp(logits - m_new) * (logits > NEG_INF / 2)
-    l_sc[...] = l_sc[...] * corr + jnp.sum(p, axis=-1, keepdims=True)
-    o_sc[...] = o_sc[...] * corr + jax.lax.dot(
-        p, v_rows, preferred_element_type=jnp.float32)
-    m_sc[...] = m_new
-
-    # -- charge-domain mode: per-slot approx probabilities for the
-    #    accumulated-score table, once all blocks are scored --
     @pl.when(j == nb - 1)
     def _flush():
         out_ref[0] = o_sc[...] / jnp.maximum(l_sc[...], 1e-30)
-        buf = score_buf[...]                                   # [G, S]
-        lg = buf * scale
-        mg = jnp.max(lg, axis=-1, keepdims=True)
-        e = jnp.exp(lg - mg) * (buf > NEG_INF / 2)
-        z = jnp.sum(e, axis=-1, keepdims=True)
-        probs_ref[0] = jnp.sum(e / jnp.maximum(z, 1e-30), axis=0)
+        probs_ref[0] = charge_probs(score_buf[...], scale)
 
 
 def _block_pad(x: jax.Array, nb: int, bs0: int, bs: int) -> jax.Array:
@@ -195,10 +218,10 @@ def fused_decode(q: jax.Array, qq: jax.Array, qscale: jax.Array,
     bs = -(-bs0 // align) * align
     s_pad = bs * nb
     if bs != bs0:
-        mirror, k, v = (_block_pad(x, nb, bs0, bs) for x in (mirror, k, v))
-        mscale, kscale, vscale, valid, prot = (
+        mirror, k, v, mscale, kscale, vscale, valid, prot = (
             _block_pad(x, nb, bs0, bs)
-            for x in (mscale, kscale, vscale, valid, prot))
+            for x in (mirror, k, v, mscale, kscale, vscale, valid, prot))
+    meta = pack_meta(mscale, kscale, vscale, valid, prot, s_pad)
     kernel = functools.partial(_fused_decode_kernel, nb=nb, bs=bs,
                                k_loc=k_loc, scale=1.0 / (d ** 0.5))
     out, probs = pl.pallas_call(
@@ -207,38 +230,32 @@ def fused_decode(q: jax.Array, qq: jax.Array, qscale: jax.Array,
         in_specs=[
             pl.BlockSpec((1, g, d), lambda i, j: (i, 0, 0)),     # q
             pl.BlockSpec((1, g, d), lambda i, j: (i, 0, 0)),     # qq
-            pl.BlockSpec((1, g), lambda i, j: (i, 0)),           # qscale
+            pl.BlockSpec((1, g, 1), lambda i, j: (i, 0, 0)),     # qscale
             pl.BlockSpec((1, bs, d), lambda i, j: (i, j, 0)),    # mirror
-            pl.BlockSpec((1, bs), lambda i, j: (i, j)),          # mscale
-            pl.BlockSpec((1, bs), lambda i, j: (i, j)),          # kscale
-            pl.BlockSpec((1, bs), lambda i, j: (i, j)),          # vscale
-            pl.BlockSpec((1, bs), lambda i, j: (i, j)),          # valid
-            pl.BlockSpec((1, bs), lambda i, j: (i, j)),          # prot
-            pl.BlockSpec(memory_space=pltpu.ANY),                # k (HBM)
-            pl.BlockSpec(memory_space=pltpu.ANY),                # v (HBM)
+            pl.BlockSpec((1, META_ROWS, bs),
+                         lambda i, j: (i, 0, j)),                # meta
+            pl.BlockSpec((1, bs, d), lambda i, j: (i, j, 0)),    # k
+            pl.BlockSpec((1, bs, dv), lambda i, j: (i, j, 0)),   # v
         ],
         out_specs=[
             pl.BlockSpec((1, g, dv), lambda i, j: (i, 0, 0)),
-            pl.BlockSpec((1, s_pad), lambda i, j: (i, 0)),
+            pl.BlockSpec((1, 1, s_pad), lambda i, j: (i, 0, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((bh, g, dv), jnp.float32),
-            jax.ShapeDtypeStruct((bh, s_pad), jnp.float32),
+            jax.ShapeDtypeStruct((bh, 1, s_pad), jnp.float32),
         ],
         scratch_shapes=[
             pltpu.VMEM((g, s_pad), jnp.float32),     # score buffer
             pltpu.VMEM((g, 1), jnp.float32),         # running max
             pltpu.VMEM((g, 1), jnp.float32),         # running denom
             pltpu.VMEM((g, dv), jnp.float32),        # running output
-            pltpu.VMEM((k_loc, d), k.dtype),         # gathered K winners
-            pltpu.VMEM((k_loc, dv), v.dtype),        # gathered V winners
-            pltpu.SemaphoreType.DMA((2,)),
         ],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-    )(q, qq, qscale.astype(jnp.float32), mirror,
-      mscale.astype(jnp.float32), kscale.astype(jnp.float32),
-      vscale.astype(jnp.float32), valid.astype(jnp.int8),
-      prot.astype(jnp.int8), k, v)
+    )(q, qq, qscale.astype(jnp.float32)[..., None], mirror, meta, k, v)
+    probs = probs[:, 0]
     if bs != bs0:   # drop the per-block alignment padding
         probs = probs.reshape(bh, nb, bs)[:, :, :bs0].reshape(bh, s)
     return out, probs

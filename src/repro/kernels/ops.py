@@ -66,8 +66,8 @@ def fused_decode(q, qq, qscale, mirror, mscale, kscale, vscale, valid,
 
     Shapes as in kernels/fused_decode.py. The XLA fallback is one fused
     region whose gather touches only the selected rows; the Pallas kernel
-    additionally keeps scores/indices out of HBM and DMAs winners row by
-    row. Returns (out [BH, G, dv], probs [BH, S]).
+    keeps scores and the winner mask in VMEM and attends over the K/V
+    blocks it streams. Returns (out [BH, G, dv], probs [BH, S]).
 
     `fills` ([BH] int32, optional): per-row live slot counts. With global
     selection (num_blocks == 1) on the Pallas backend this dispatches the

@@ -8,19 +8,25 @@ O(1) per-array CAM race for a *mixed* batch:
 
   * the per-row live-block count ``ceil(fill / bs)`` is SCALAR-PREFETCHED,
     so it is available to the index maps before the kernel body runs;
-  * dead k-blocks (block index >= live count) remap their mirror DMA to
-    the last live block — Pallas elides the copy when consecutive grid
-    steps fetch the same block, so a dead block moves no mirror bytes;
-  * ``pl.when`` skips the dead block's scoring entirely — a short lane
-    pays O(fill) compute + bandwidth while a long lane in the same batch
-    pays its own O(fill), instead of everyone paying O(max over lanes).
+  * dead blocks (block index >= live count) remap their DMAs to a block
+    the pipeline already holds — Pallas elides the copy when consecutive
+    grid steps fetch the same block, so a dead block moves no bytes;
+  * ``pl.when`` skips the dead block's work entirely — a short lane pays
+    O(fill) compute + bandwidth while a long lane in the same batch pays
+    its own O(fill), instead of everyone paying O(max over lanes).
 
 Selection is GLOBAL top-k (the ``num_blocks == 1`` semantics of the fused
-kernel / ``ref.fused_decode_ref``): scores accumulate into a VMEM buffer
-initialised to NEG_INF — dead regions therefore rank exactly like invalid
-slots — and the last grid step runs the race, DMAs only the winners' K/V
-rows from HBM, and emits the exact attention output plus the per-slot
-charge-domain probabilities.
+kernel / ``ref.fused_decode_ref``), so each row walks its blocks twice —
+grid (BH, 2·nb):
+
+  steps [0, nb)    CAM pass: score the live mirror blocks into a VMEM
+                   buffer initialised to NEG_INF (dead regions therefore
+                   rank exactly like invalid slots); the last step runs
+                   the race over the whole row and keeps the winner mask;
+  steps [nb, 2nb)  current pass: stream the live K/V blocks and attend
+                   over their winners (online softmax); the last step
+                   emits the output and the per-slot charge-domain
+                   probabilities.
 
   fills  [BH]        int32           live slot count per row (lane fill)
   q      [BH, G, d]  storage dtype   exact queries
@@ -32,10 +38,14 @@ charge-domain probabilities.
   vscale [BH, S]     f32
   valid  [BH, S]     int8
   prot   [BH, S]     int8            protected slots always win the race
-  k      [BH, S, d]  ANY/HBM         exact keys   — winners DMA'd only
-  v      [BH, S, dv] ANY/HBM         exact values — winners DMA'd only
+  k      [BH, S, d]  storage dtype   exact keys
+  v      [BH, S, dv] storage dtype   exact values
   out    [BH, G, dv] f32
   probs  [BH, S]     f32             Σ_g softmax_g(scores/√d)
+
+S need not be a multiple of the block: the last block is partial, its
+out-of-range slots are invalid in the packed metadata and its V rows are
+zeroed in-kernel, so mirror/K/V are read in place with no padded copy.
 
 Composition with the in-place decode path: this kernel is a pure READ of
 the cache arrays (its fill-aware block skipping is the kernel-level
@@ -55,110 +65,59 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-NEG_INF = -1e30
-PROT_WIN = 1e30
-PICKED = -1e35
+from repro.kernels.fused_decode import (META_ROWS, NEG_INF, PROT, PROT_WIN,
+                                        attend_block, cam_scores,
+                                        charge_probs, init_softmax,
+                                        lane_offset, pack_meta, race)
 
 
-def _ragged_decode_kernel(nblk_ref, q_ref, qq_ref, qs_ref, mir_ref, ms_ref,
-                          ks_ref, vs_ref, valid_ref, prot_ref, k_any, v_any,
-                          out_ref, probs_ref,
-                          score_buf, ksel, vsel, sem,
-                          *, nb, bs, s_pad, k_sel_n, scale):
+def _ragged_decode_kernel(nblk_ref, q_ref, qq_ref, qs_ref, mir_ref,
+                          meta_ref, k_ref, v_ref, out_ref, probs_ref,
+                          score_buf, sel_buf, m_sc, l_sc, o_sc,
+                          *, nb, bs, s, k_sel_n, scale):
     i = pl.program_id(0)
     j = pl.program_id(1)
     live_blocks = nblk_ref[i]
+    g = score_buf.shape[0]
 
     @pl.when(j == 0)
     def _init():
         # dead regions keep NEG_INF: they race exactly like invalid slots
         score_buf[...] = jnp.full_like(score_buf, NEG_INF)
+        sel_buf[...] = jnp.full_like(sel_buf, g * NEG_INF)
+        init_softmax(m_sc, l_sc, o_sc)
 
-    # -- CAM mode: score this block iff it holds any live slot --
+    # -- CAM pass: score this block iff it holds any live slot --
     @pl.when(j < live_blocks)
     def _score():
-        qqf = qq_ref[0].astype(jnp.float32)                # [G, d]
-        mir = mir_ref[0].astype(jnp.float32)               # [bs, d]
-        raw = jax.lax.dot_general(
-            qqf, mir, dimension_numbers=(((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)            # [G, bs]
-        ms = ms_ref[0, pl.ds(j * bs, bs)]
-        raw = raw * qs_ref[0][:, None] * ms[None, :]
-        validb = valid_ref[0, pl.ds(j * bs, bs)][None, :] != 0
-        score_buf[:, pl.ds(j * bs, bs)] = jnp.where(validb, raw, NEG_INF)
+        meta = meta_ref[0]                                 # [8, bs]
+        raw = cam_scores(qq_ref[0], qs_ref[0], mir_ref[0], meta)
+        off = lane_offset(j, bs)
+        score_buf[:, pl.ds(off, bs)] = raw
+        ssel = jnp.sum(raw, axis=0, keepdims=True)         # [1, bs]
+        sel_buf[:, pl.ds(off, bs)] = jnp.where(
+            meta[PROT:PROT + 1] != 0, PROT_WIN, ssel)
 
-    # -- final grid step: global CAM race + winner DMA + exact attention --
+    # -- global CAM race over the whole row; keep the winner mask --
     @pl.when(j == nb - 1)
-    def _select_attend():
-        buf = score_buf[...]                               # [G, S_pad]
-        ssel = jnp.sum(buf, axis=0, keepdims=True)         # [1, S_pad]
-        ssel = jnp.where(prot_ref[0][None, :] != 0, PROT_WIN, ssel)
-        iota_s = jax.lax.broadcasted_iota(jnp.int32, (1, s_pad), 1)
-        iota_k = jax.lax.broadcasted_iota(jnp.int32, (k_sel_n, 1), 0)
+    def _race():
+        sel_buf[...] = jnp.where(race(sel_buf[...], k_sel_n), 1.0, 0.0)
 
-        def _copies(slot_idx, t):
-            return (pltpu.make_async_copy(k_any.at[i, pl.ds(slot_idx, 1)],
-                                          ksel.at[pl.ds(t, 1)], sem.at[0]),
-                    pltpu.make_async_copy(v_any.at[i, pl.ds(slot_idx, 1)],
-                                          vsel.at[pl.ds(t, 1)], sem.at[1]))
+    # -- current pass: exact attention over each live block's winners --
+    jj = j - nb
 
-        def select_one(t, carry):
-            sc, onehot, prev = carry
-            idx = jnp.argmax(sc).astype(jnp.int32)         # first max wins
-            row = iota_s == idx
-            onehot = onehot + jnp.where((iota_k == t) & row, 1.0, 0.0)
-            # depth-1 DMA pipeline, as in the fused kernel
+    @pl.when((jj >= 0) & (jj < live_blocks))
+    def _attend():
+        sel = sel_buf[:, pl.ds(lane_offset(jj, bs), bs)] > 0.5
+        attend_block(q_ref[0], k_ref[0], v_ref[0], meta_ref[0], sel, scale,
+                     m_sc, l_sc, o_sc,
+                     n_valid=None if s % bs == 0 else s - jj * bs)
 
-            @pl.when(t > 0)
-            def _drain_prev():
-                for cp in _copies(prev, t - 1):
-                    cp.wait()
-
-            for cp in _copies(idx, t):
-                cp.start()
-            return jnp.where(row, PICKED, sc), onehot, idx
-
-        carry0 = (ssel, jnp.zeros((k_sel_n, s_pad), jnp.float32),
-                  jnp.int32(0))
-        _, onehot, last = jax.lax.fori_loop(0, k_sel_n, select_one, carry0)
-        for cp in _copies(last, k_sel_n - 1):
-            cp.wait()
-
-        sel_ks = jax.lax.dot(onehot, ks_ref[0][:, None],
-                             preferred_element_type=jnp.float32)
-        sel_vs = jax.lax.dot(onehot, vs_ref[0][:, None],
-                             preferred_element_type=jnp.float32)
-        sel_valid = jax.lax.dot(
-            onehot, (valid_ref[0][:, None]).astype(jnp.float32),
-            preferred_element_type=jnp.float32)            # [k, 1]
-
-        k_rows = ksel[...].astype(jnp.float32) * sel_ks    # [k, d]
-        v_rows = vsel[...].astype(jnp.float32) * sel_vs    # [k, dv]
-        qf = q_ref[0].astype(jnp.float32)                  # [G, d]
-        logits = jax.lax.dot_general(
-            qf, k_rows, dimension_numbers=(((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale    # [G, k]
-        logits = jnp.where(sel_valid[:, 0][None, :] > 0.5, logits, NEG_INF)
-        m = jnp.max(logits, axis=-1, keepdims=True)
-        e = jnp.exp(logits - m) * (logits > NEG_INF / 2)
-        z = jnp.maximum(jnp.sum(e, axis=-1, keepdims=True), 1e-30)
-        out_ref[0] = jax.lax.dot(e / z, v_rows,
-                                 preferred_element_type=jnp.float32)
-
+    @pl.when(j == 2 * nb - 1)
+    def _flush():
+        out_ref[0] = o_sc[...] / jnp.maximum(l_sc[...], 1e-30)
         # -- charge-domain mode: per-slot approximate probabilities --
-        lg = buf * scale
-        mg = jnp.max(lg, axis=-1, keepdims=True)
-        eg = jnp.exp(lg - mg) * (buf > NEG_INF / 2)
-        zg = jnp.sum(eg, axis=-1, keepdims=True)
-        probs_ref[0] = jnp.sum(eg / jnp.maximum(zg, 1e-30), axis=0)
-
-
-def _pad_tail(x, s_pad, value=0):
-    pad = s_pad - x.shape[1]
-    if pad == 0:
-        return x
-    widths = [(0, 0), (0, pad)] + [(0, 0)] * (x.ndim - 2)
-    return jnp.pad(x, widths, constant_values=value)
+        probs_ref[0] = charge_probs(score_buf[...], scale)
 
 
 @functools.partial(jax.jit,
@@ -172,10 +131,9 @@ def ragged_decode(fills: jax.Array, q: jax.Array, qq: jax.Array,
                   interpret: bool = False, block_align: int = 0):
     """Fill-aware fused decode. Returns (out [BH,G,dv], probs [BH,S]).
 
-    Global (num_blocks == 1) selection semantics — bitwise-compatible
-    with ``ref.fused_decode_ref(..., num_blocks=1)`` whenever slots at
-    and beyond ``fills[i]`` are invalid (the cache write discipline).
-    Trailing padding to a block multiple is appended as invalid slots;
+    Global (num_blocks == 1) selection semantics — matches
+    ``ref.fused_decode_ref(..., num_blocks=1)`` whenever slots at and
+    beyond ``fills[i]`` are invalid (the cache write discipline).
     block_align=0 picks the backend default (none in interpret mode,
     128 lanes on TPU)."""
     bh, g, d = q.shape
@@ -186,46 +144,52 @@ def ragged_decode(fills: jax.Array, q: jax.Array, qq: jax.Array,
     bs = -(-min(block_s, s) // align) * align
     s_pad = -(-s // bs) * bs
     nb = s_pad // bs
-    mirror, k, v = (_pad_tail(x, s_pad) for x in (mirror, k, v))
-    mscale, kscale, vscale, valid, prot = (
-        _pad_tail(x, s_pad) for x in (mscale, kscale, vscale, valid, prot))
+    meta = pack_meta(mscale, kscale, vscale, valid, prot, s_pad)
     nblk = jnp.clip(-(-jnp.minimum(fills.astype(jnp.int32), s) // bs),
                     0, nb)
 
     def blk(j, cnt):
         # dead blocks re-fetch the last live block: the pipeline sees an
-        # unchanged block index and elides the mirror copy entirely
+        # unchanged block index and elides the copy entirely
         return jnp.maximum(jnp.minimum(j, cnt - 1), 0)
 
-    kernel = functools.partial(_ragged_decode_kernel, nb=nb, bs=bs,
-                               s_pad=s_pad, k_sel_n=select_k,
-                               scale=1.0 / (d ** 0.5))
+    def score_blk(i, j, c):        # CAM pass walks 0..live-1, then holds
+        return blk(jnp.minimum(j, nb - 1), c[i])
+
+    def attend_blk(i, j, c):       # held at block 0 until the current pass
+        return blk(jnp.maximum(j - nb, 0), c[i])
+
+    def meta_blk(i, j, c):
+        return blk(jnp.where(j < nb, j, j - nb), c[i])
+
+    kernel = functools.partial(_ragged_decode_kernel, nb=nb, bs=bs, s=s,
+                               k_sel_n=select_k, scale=1.0 / (d ** 0.5))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
-        grid=(bh, nb),
+        grid=(bh, 2 * nb),
         in_specs=[
             pl.BlockSpec((1, g, d), lambda i, j, c: (i, 0, 0)),   # q
             pl.BlockSpec((1, g, d), lambda i, j, c: (i, 0, 0)),   # qq
-            pl.BlockSpec((1, g), lambda i, j, c: (i, 0)),         # qscale
+            pl.BlockSpec((1, g, 1), lambda i, j, c: (i, 0, 0)),   # qscale
             pl.BlockSpec((1, bs, d),
-                         lambda i, j, c: (i, blk(j, c[i]), 0)),   # mirror
-            pl.BlockSpec((1, s_pad), lambda i, j, c: (i, 0)),     # mscale
-            pl.BlockSpec((1, s_pad), lambda i, j, c: (i, 0)),     # kscale
-            pl.BlockSpec((1, s_pad), lambda i, j, c: (i, 0)),     # vscale
-            pl.BlockSpec((1, s_pad), lambda i, j, c: (i, 0)),     # valid
-            pl.BlockSpec((1, s_pad), lambda i, j, c: (i, 0)),     # prot
-            pl.BlockSpec(memory_space=pltpu.ANY),                 # k (HBM)
-            pl.BlockSpec(memory_space=pltpu.ANY),                 # v (HBM)
+                         lambda i, j, c: (i, score_blk(i, j, c), 0)),
+            pl.BlockSpec((1, META_ROWS, bs),
+                         lambda i, j, c: (i, 0, meta_blk(i, j, c))),
+            pl.BlockSpec((1, bs, d),
+                         lambda i, j, c: (i, attend_blk(i, j, c), 0)),
+            pl.BlockSpec((1, bs, dv),
+                         lambda i, j, c: (i, attend_blk(i, j, c), 0)),
         ],
         out_specs=[
             pl.BlockSpec((1, g, dv), lambda i, j, c: (i, 0, 0)),
-            pl.BlockSpec((1, s_pad), lambda i, j, c: (i, 0)),
+            pl.BlockSpec((1, 1, s_pad), lambda i, j, c: (i, 0, 0)),
         ],
         scratch_shapes=[
             pltpu.VMEM((g, s_pad), jnp.float32),     # score buffer
-            pltpu.VMEM((select_k, d), k.dtype),      # gathered K winners
-            pltpu.VMEM((select_k, dv), v.dtype),     # gathered V winners
-            pltpu.SemaphoreType.DMA((2,)),
+            pltpu.VMEM((1, s_pad), jnp.float32),     # race input → winners
+            pltpu.VMEM((g, 1), jnp.float32),         # running max
+            pltpu.VMEM((g, 1), jnp.float32),         # running denom
+            pltpu.VMEM((g, dv), jnp.float32),        # running output
         ],
     )
     out, probs = pl.pallas_call(
@@ -233,11 +197,11 @@ def ragged_decode(fills: jax.Array, q: jax.Array, qq: jax.Array,
         grid_spec=grid_spec,
         out_shape=[
             jax.ShapeDtypeStruct((bh, g, dv), jnp.float32),
-            jax.ShapeDtypeStruct((bh, s_pad), jnp.float32),
+            jax.ShapeDtypeStruct((bh, 1, s_pad), jnp.float32),
         ],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-    )(nblk, q, qq, qscale.astype(jnp.float32), mirror,
-      mscale.astype(jnp.float32), kscale.astype(jnp.float32),
-      vscale.astype(jnp.float32), valid.astype(jnp.int8),
-      prot.astype(jnp.int8), k, v)
-    return out, probs[:, :s]
+    )(nblk, q, qq, qscale.astype(jnp.float32)[..., None], mirror, meta,
+      k, v)
+    return out, probs[:, 0, :s]

@@ -9,17 +9,24 @@ from __future__ import annotations
 import jax
 
 
+def make_mesh(shape, axes, devices=None):
+    """`jax.make_mesh` with Auto axes: the repo's sharding rules rely on
+    GSPMD propagation, while `make_mesh` defaults to Explicit axes."""
+    types = (jax.sharding.AxisType.Auto,) * len(axes)
+    return jax.make_mesh(shape, axes, axis_types=types, devices=devices)
+
+
 def make_production_mesh(*, multi_pod: bool = False):
     """16×16 single pod (256 chips) or 2×16×16 multi-pod (512 chips)."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_host_mesh():
     """Whatever devices exist right now (tests / smoke runs)."""
     n = len(jax.devices())
-    return jax.make_mesh((1, n), ("data", "model"))
+    return make_mesh((1, n), ("data", "model"))
 
 
 def make_serve_mesh(n_shards: int | None = None):
@@ -31,7 +38,7 @@ def make_serve_mesh(n_shards: int | None = None):
     """
     devs = jax.devices()
     n = n_shards or len(devs)
-    return jax.make_mesh((n,), ("data",), devices=devs[:n])
+    return make_mesh((n,), ("data",), devices=devs[:n])
 
 
 def make_elastic_mesh(n_devices: int | None = None):
@@ -44,5 +51,4 @@ def make_elastic_mesh(n_devices: int | None = None):
         if n % cand == 0:
             model = cand
             break
-    return jax.make_mesh((n // model, model), ("data", "model"),
-                         devices=devs[:n])
+    return make_mesh((n // model, model), ("data", "model"), devices=devs[:n])

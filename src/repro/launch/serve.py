@@ -65,6 +65,7 @@ import numpy as np
 
 from repro.configs.base import get_config, reduced
 from repro.core import baselines
+from repro.core.attention import decode_engine
 from repro.launch.prefix_cache import PrefixCache, RowsEntry, StateEntry
 from repro.models.transformer import Model
 from repro.surgery import (cache_prefix_rows, state_lane_insert,
@@ -407,7 +408,7 @@ def decode_block_lanes_sharded(model: Model, mesh, params, state, tok,
     """
     from jax.sharding import PartitionSpec as P
 
-    from repro.runtime.compat import shard_map
+    from jax import shard_map
     from repro.runtime.sharding import lane_pspecs
 
     state_specs = lane_pspecs(state, mesh)
@@ -1029,6 +1030,11 @@ class ServeLoop:
             self.shards = int(mesh.shape["data"])
             assert lanes % self.shards == 0, (
                 f"lanes={lanes} not divisible by {self.shards} shards")
+            # replicate the weights once: every shard reads all of them,
+            # and weights left on one device would be re-broadcast to the
+            # mesh on every dispatch
+            from jax.sharding import NamedSharding, PartitionSpec as P
+            self.params = jax.device_put(params, NamedSharding(mesh, P()))
         self.lanes_per_shard = lanes // self.shards
         self._shard_tokens = np.zeros(self.shards, np.int64)
         self._state_shardings = None          # built lazily with the state
@@ -2486,33 +2492,9 @@ class ServeLoop:
             self.counters["chaos_faults"] += int(fault.sum())
         else:
             fault = np.zeros((steps, self.lanes), bool)
-        if self.mesh is None:
-            def put(a, dtype=None):
-                return jnp.asarray(a, dtype)
-            fault_dev = jnp.asarray(fault)
-        else:
-            # commit every host-side lane array to the P("data") layout
-            # (and re-pin the state after any admission splice) so the
-            # shard_map'd block never inserts input reshards
-            self._pin_state()
-            lane_sh = self._lane_sharding()
-
-            def put(a, dtype=None):
-                return jax.device_put(np.asarray(a, dtype), lane_sh)
-            from jax.sharding import NamedSharding, PartitionSpec as P
-            fault_dev = jax.device_put(
-                fault, NamedSharding(self.mesh, P(None, "data")))
         t_disp = time.monotonic()
         (self.state, self.tok, active, rem, keys, poison, toks,
-         emitted) = fn(
-            self.params, self.state, self.tok,
-            put(self.active), put(self.remaining),
-            put(self.lane_eos, np.int32),
-            put(self._lane_keys, np.uint32),
-            put(self.lane_temp, np.float32),
-            put(self.lane_topk, np.int32),
-            put(self.lane_topp, np.float32),
-            fault_dev)
+         emitted) = fn(*self._block_args(fault))
         self._lane_keys = np.asarray(keys).astype(np.uint32)
         self.counters["decode_blocks"] += 1
         # knob values ride in as [lanes] arrays, so the jit cache holds ONE
@@ -2550,6 +2532,34 @@ class ServeLoop:
         for lane in np.flatnonzero(host_poison & was_active):
             self._quarantine_lane(int(lane), now)
         return bool(self.active.any())
+
+    def _block_args(self, fault: np.ndarray) -> tuple:
+        """The decode block's arguments from the live engine state, with
+        `fault` ([steps, lanes] bool) as the injected-fault mask."""
+        if self.mesh is None:
+            def put(a, dtype=None):
+                return jnp.asarray(a, dtype)
+            fault_dev = jnp.asarray(fault)
+        else:
+            # commit every host-side lane array to the P("data") layout
+            # (and re-pin the state after any admission splice) so the
+            # shard_map'd block never inserts input reshards
+            self._pin_state()
+            lane_sh = self._lane_sharding()
+
+            def put(a, dtype=None):
+                return jax.device_put(np.asarray(a, dtype), lane_sh)
+            from jax.sharding import NamedSharding, PartitionSpec as P
+            fault_dev = jax.device_put(
+                fault, NamedSharding(self.mesh, P(None, "data")))
+        return (self.params, self.state, self.tok,
+                put(self.active), put(self.remaining),
+                put(self.lane_eos, np.int32),
+                put(self._lane_keys, np.uint32),
+                put(self.lane_temp, np.float32),
+                put(self.lane_topk, np.int32),
+                put(self.lane_topp, np.float32),
+                fault_dev)
 
     def _quarantine_lane(self, lane: int, now: float) -> None:
         """One lane tripped the non-finite sentinel: free it (its state
@@ -2798,8 +2808,16 @@ def main(argv=None):
     ap.add_argument("--reduced", action="store_true")
     ap.add_argument("--policy", default="unicaim",
                     choices=["unicaim", "h2o", "streaming", "dense"])
-    ap.add_argument("--fused", action="store_true",
-                    help="single-pass fused decode engine (unicaim only)")
+    engine_flags = ap.add_mutually_exclusive_group()
+    engine_flags.add_argument(
+        "--fused", dest="fused", action="store_const", const=True,
+        default="auto",
+        help="force the fused single-pass decode engine (unicaim only; "
+             "default: auto — the Pallas kernels on TPU, the composed "
+             "path elsewhere)")
+    engine_flags.add_argument(
+        "--composed", dest="fused", action="store_const", const=False,
+        help="force the composed three-pass decode engine")
     ap.add_argument("--no-scan", action="store_true",
                     help="per-token Python loop instead of lax.scan")
     ap.add_argument("--serve", action="store_true",
@@ -2831,6 +2849,8 @@ def main(argv=None):
                     help="always decode at full slot width instead of "
                          "the fill-covering pow2 window (--serve only)")
     args = ap.parse_args(argv)
+    from repro.runtime.compile_cache import configure_compile_cache
+    configure_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:
@@ -2847,6 +2867,7 @@ def main(argv=None):
     else:
         prune = baselines.dense(args.prompt_len + args.new_tokens)
     model = Model(cfg, prune)
+    engine = decode_engine(prune, latent_cache=cfg.mla is not None)
     params = model.init(jax.random.PRNGKey(0))
     rng = np.random.default_rng(0)
 
@@ -2875,7 +2896,7 @@ def main(argv=None):
                   f"bucket={s.bucket} chunks={s.prefill_chunks} "
                   f"new={len(s.tokens)} latency={s.latency:.2f}s "
                   f"ttft={s.ttft:.2f}s occ={s.occupancy:.2f}")
-        print(f"arch={cfg.name} policy={args.policy} fused={args.fused} "
+        print(f"arch={cfg.name} policy={args.policy} engine={engine} "
               f"served {len(stats)} reqs on {args.batch} lanes in {dt:.2f}s "
               f"({agg['tokens_per_s']:.1f} tok/s, "
               f"p99_ttft={agg['p99_ttft_s']:.2f}s, "
@@ -2902,7 +2923,7 @@ def main(argv=None):
     dt = time.time() - t0
     mode = "loop" if args.no_scan else "scan"
     print(f"arch={cfg.name} policy={args.policy} mode={mode} "
-          f"fused={args.fused} cache_slots={prune.slots} "
+          f"engine={engine} cache_slots={prune.slots} "
           f"generated {toks.shape} in {dt:.2f}s "
           f"({args.batch * args.new_tokens / dt:.1f} tok/s)")
 

@@ -136,7 +136,7 @@ def _mla_blocked_shardmap(cache: KVCache, q_full: jax.Array,
                           kv_lora: int, scale_dim: int) -> jax.Array:
     """Shard-local latent selection for MLA decode (distributed CAM race
     over the latent mirror). Returns ctx [B, H, kv_lora]."""
-    from repro.runtime.compat import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
     from repro.core.attention import _slot_axes
 

@@ -71,7 +71,7 @@ def apply_moe_ep_shardmap(p, x, cfg: ModelConfig, mesh,
     token payloads — the textbook EP schedule. Requires n_experts and
     tokens divisible by the model-axis size.
     """
-    from repro.runtime.compat import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     mo: MoEConfig = cfg.moe

@@ -18,7 +18,7 @@ from typing import Any, NamedTuple, Tuple
 
 import jax
 import jax.numpy as jnp
-from repro.runtime.compat import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 

@@ -13,6 +13,7 @@ SCRIPT = textwrap.dedent("""
     import os
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     import jax, jax.numpy as jnp, numpy as np
+    from repro.launch.mesh import make_mesh
     from jax.sharding import NamedSharding, PartitionSpec as P
     from repro.checkpoint.manager import CheckpointManager
     from repro.configs.base import get_config, reduced
@@ -24,7 +25,7 @@ SCRIPT = textwrap.dedent("""
     cfg = reduced(get_config("granite-3-2b"), n_heads=4, n_kv_heads=2)
     model = Model(cfg, baselines.unicaim(48, 16, 16))
 
-    mesh_a = jax.make_mesh((2, 4), ("data", "model"))
+    mesh_a = make_mesh((2, 4), ("data", "model"))
     with use_mesh(mesh_a):
         params = model.init(jax.random.PRNGKey(0))
         sh = jax.tree.map(lambda s: NamedSharding(mesh_a, s),
@@ -37,7 +38,7 @@ SCRIPT = textwrap.dedent("""
     flat_a = [np.asarray(x) for x in jax.tree.leaves(params)]
 
     # "cluster shrinks": restore onto a 4x2 mesh with recomputed shardings
-    mesh_b = jax.make_mesh((4, 2), ("data", "model"))
+    mesh_b = make_mesh((4, 2), ("data", "model"))
     template = jax.tree.map(
         lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), params)
     with use_mesh(mesh_b):

@@ -2,10 +2,11 @@
 a tiny-mesh pjit end-to-end check (runs on however many host devices exist)."""
 import jax
 import numpy as np
-from jax.sharding import Mesh, PartitionSpec as P
+from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import PruneConfig, get_config, reduced
 from repro.core import baselines
+from repro.launch.mesh import make_mesh
 from repro.models.transformer import Model
 from repro.runtime.sharding import (decode_state_pspecs, logical_to_spec,
                                     params_pspecs, use_mesh)
@@ -13,16 +14,9 @@ from repro.runtime.sharding import (decode_state_pspecs, logical_to_spec,
 jax.config.update("jax_platform_name", "cpu")
 
 
-def _fake_mesh(shape, axes):
-    """Abstract mesh over fake devices for spec computation only."""
-    try:
-        return jax.sharding.AbstractMesh(shape, axes)
-    except TypeError:  # older jax: single tuple of (name, size) pairs
-        return jax.sharding.AbstractMesh(tuple(zip(axes, shape)))
-
-
-MESH = _fake_mesh((16, 16), ("data", "model"))
-MESH3 = _fake_mesh((2, 16, 16), ("pod", "data", "model"))
+# abstract meshes over fake devices, for spec computation only
+MESH = jax.sharding.AbstractMesh((16, 16), ("data", "model"))
+MESH3 = jax.sharding.AbstractMesh((2, 16, 16), ("pod", "data", "model"))
 
 
 def test_divisibility_fallback_replicates():
@@ -45,7 +39,7 @@ def test_param_rules_attention_and_moe():
                   d_model=64, n_heads=16, n_kv_heads=16, head_dim=16)
     model = Model(cfg, baselines.unicaim(48, 16, 16))
     shapes = jax.eval_shape(model.init, jax.random.PRNGKey(0))
-    with use_mesh(_fake_mesh((2, 2), ("data", "model"))):
+    with use_mesh(jax.sharding.AbstractMesh((2, 2), ("data", "model"))):
         specs = params_pspecs(shapes)
     flat = jax.tree_util.tree_flatten_with_path(specs)[0]
     by_name = {"/".join(str(getattr(k, "key", getattr(k, "name", k)))
@@ -95,7 +89,7 @@ def test_pjit_end_to_end_tiny_mesh():
     """Real pjit run on the host's devices (1 on CI — still exercises the
     NamedSharding path)."""
     n = len(jax.devices())
-    mesh = jax.make_mesh((1, n), ("data", "model"))
+    mesh = make_mesh((1, n), ("data", "model"))
     cfg = reduced(get_config("granite-3-2b"), n_heads=4, n_kv_heads=2)
     prune = baselines.unicaim(heavy=48, reserve=16, select_k=16,
                               sink_tokens=2, recent_window=8)

@@ -10,6 +10,7 @@ SCRIPT = textwrap.dedent("""
     import os
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     import jax, jax.numpy as jnp, numpy as np
+    from repro.launch.mesh import make_mesh
     from jax.sharding import NamedSharding, PartitionSpec as P
     from repro.configs.base import PruneConfig
     from repro.core.attention import decode_attention
@@ -42,7 +43,7 @@ SCRIPT = textwrap.dedent("""
         return np.stack(outs)
 
     ref = run(None)                       # pure blocked path, 1 device
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     got = run(mesh)                       # shard_map path (blocks=model=4)
     np.testing.assert_allclose(got, ref, atol=2e-4)
     print("SHARDMAP_OK")
@@ -63,6 +64,7 @@ MLA_SCRIPT = textwrap.dedent("""
     import os
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     import jax, jax.numpy as jnp, numpy as np
+    from repro.launch.mesh import make_mesh
     from repro.configs.base import PruneConfig
     from repro.core import quant, scoring, topk
     from repro.core.cache import init_cache, protected_mask, write_token
@@ -101,7 +103,7 @@ MLA_SCRIPT = textwrap.dedent("""
     pr = jax.nn.softmax(logits, axis=-1)
     ref = jnp.einsum("bhs,bsk->bhk", pr, u_all[:, :, :KVR])
 
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     with use_mesh(mesh):
         got = _mla_blocked_shardmap(cache, q_full, biased, prune, mesh,
                                     KVR, scale_dim)
@@ -124,6 +126,7 @@ MOE_EP_SCRIPT = textwrap.dedent("""
     import os
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     import jax, jax.numpy as jnp, numpy as np, dataclasses
+    from repro.launch.mesh import make_mesh
     from repro.configs.base import get_config, reduced
     from repro.models.moe import apply_moe, apply_moe_ep_shardmap, init_moe
     from repro.runtime.sharding import use_mesh
@@ -134,7 +137,7 @@ MOE_EP_SCRIPT = textwrap.dedent("""
     params = init_moe(jax.random.PRNGKey(0), cfg, jnp.float32)
     x = jax.random.normal(jax.random.PRNGKey(1), (4, 16, cfg.d_model))
     y_ref, _ = apply_moe(params, x, cfg)
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     with use_mesh(mesh):
         y_ep, _ = jax.jit(lambda p, x: apply_moe_ep_shardmap(
             p, x, cfg, mesh))(params, x)
