@@ -20,8 +20,10 @@ served pass that is timed. It fails unless
   * the ragged kernel matches the pure-jnp oracle at the served width
     within KERNEL_ATOL;
   * one decode step's logits from the kernel engine and from the composed
-    engine (`fused=False`), on the same served state, agree within the
-    bound stated at LOGITS_RTOL_FLOOR.
+    engine (`fused=False`), on the same served state, agree within
+    LOGITS_RTOL, a planted selection fault lands above it, and the two
+    engines' greedy tokens differ only where the composed engine's own
+    top-two margin is within that tolerance.
 
 Phase "mesh" (--chips 4) replays the same trace through
 `ServeLoop(mesh=4, lanes=8)` and through the unsharded engine on one chip
@@ -53,14 +55,17 @@ PROMPT_MAX = 2048
 # so they differ by f32 summation order and exp rounding only.
 KERNEL_ATOL = 1e-4
 # Kernel engine vs composed engine, one decode step, same state, both at
-# matmul precision HIGHEST: relative RMS of the logits difference may not
-# exceed the composed engine's own spread between default and HIGHEST
-# precision (measured in the same run), nor LOGITS_RTOL_FLOOR if that
-# spread is smaller. A correct kernel differs from the composed engine by
-# f32 summation order, far below the bf16 roundings that default
-# precision puts into the composed attention; a selection fault moves
-# whole winners in or out of the attention and lands far above both.
-LOGITS_RTOL_FLOOR = 1e-2
+# matmul precision HIGHEST: relative RMS of the logits difference. Both
+# engines compute the attention in f32 and differ by f32 summation order;
+# the bf16 activations between layers round such differences up to
+# bf16-ulp flips that the 40 random-weight layers carry to the logits.
+# Attention outputs perturbed by 1e-7..1e-5 relative noise moved the
+# logits of an 8- and a 24-layer bf16 granite stack by 7.5e-3..1.6e-2,
+# while one winner too few per row (select_k - 1) moved them by
+# 0.28..0.38. The bound sits between the two, and every run checks that
+# the planted fault (the kernel engine at select_k - 1, same state) does
+# exceed it, so the check is shown to see a one-winner selection error.
+LOGITS_RTOL = 5e-2
 COLLECTIVES = ("all-gather", "all-reduce", "collective-permute",
                "all-to-all", "reduce-scatter")
 
@@ -233,15 +238,25 @@ def check_kernel(model, seed: int):
 
 def compare_engines(cfg, model, params, state, tok):
     """One decode step's logits from the kernel and the composed engine on
-    the same state, at default and at HIGHEST matmul precision."""
+    the same state at HIGHEST matmul precision, a planted selection fault
+    (the kernel engine at select_k - 1) against the same reference, and
+    each engine's step wall time at default precision."""
     import jax
     import numpy as np
     from repro.models.transformer import Model
 
-    composed = Model(cfg, dataclasses.replace(model.prune, fused=False))
+    prune = model.prune
+    engines = {
+        "kernel": model,
+        "composed": Model(cfg, dataclasses.replace(prune, fused=False)),
+        "planted": Model(cfg, dataclasses.replace(
+            prune, select_k=prune.select_k - 1)),
+    }
     logits, steps = {}, {}
-    for name, m in (("kernel", model), ("composed", composed)):
+    for name, m in engines.items():
         for prec in ("default", "highest"):
+            if name == "planted" and prec == "default":
+                continue
             with jax.default_matmul_precision(prec):
                 step = jax.jit(m.decode_step)
                 out, _ = step(params, state, tok)
@@ -250,25 +265,39 @@ def compare_engines(cfg, model, params, state, tok):
                     steps[name] = wall_ms(step, params, state, tok)
     check(all(np.isfinite(v).all() for v in logits.values()),
           "non-finite logits")
-    hi = rel_rms(logits["kernel", "highest"], logits["composed", "highest"])
-    spread = rel_rms(logits["composed", "default"],
-                     logits["composed", "highest"])
+    ref = logits["composed", "highest"]
+    hi = rel_rms(logits["kernel", "highest"], ref)
+    planted = rel_rms(logits["planted", "highest"], ref)
+    spread = rel_rms(logits["composed", "default"], ref)
     served = rel_rms(logits["kernel", "default"],
                      logits["composed", "default"])
-    bound = max(spread, LOGITS_RTOL_FLOOR)
-    n = logits["kernel", "highest"].shape[0]
-    agree = int((logits["kernel", "highest"].argmax(-1)
-                 == logits["composed", "highest"].argmax(-1)).sum())
+    # greedy tokens may differ only where the composed engine's top two
+    # logits are closer than the tolerated noise: random-weight logits
+    # are flat, so such near-ties do occur
+    win_k = logits["kernel", "highest"].argmax(-1)
+    win_c = ref.argmax(-1)
+    rows = np.arange(len(ref))
+    margin = ref[rows, win_c] - ref[rows, win_k]
+    noise = LOGITS_RTOL * np.sqrt(np.mean(ref ** 2, axis=-1))
+    n = len(ref)
     log(f"logits kernel vs composed (one decode step, {n} lanes, same "
-        f"state): rel_rms at HIGHEST {hi:.3e} (bound {bound:.3e} = max("
-        f"composed default-vs-HIGHEST spread {spread:.3e}, floor "
-        f"{LOGITS_RTOL_FLOOR:.0e})); argmax agree {agree}/{n}; at default "
-        f"precision rel_rms {served:.3e}")
+        f"state, HIGHEST): rel_rms {hi:.3e} (bound {LOGITS_RTOL:.0e}); "
+        f"planted fault select_k-1: rel_rms {planted:.3e} (must exceed the "
+        f"bound); argmax agree {int((win_k == win_c).sum())}/{n}, composed "
+        f"margin at disagreements {margin[win_k != win_c].tolist()} "
+        f"(allowed <= {noise.min():.3e})")
+    log(f"printed, not checked: composed default-vs-HIGHEST spread "
+        f"{spread:.3e}; kernel vs composed at default precision {served:.3e}")
     log(f"decode step wall ms (default precision, this state, one-off): "
         f"kernel engine {steps['kernel']:.2f}, composed engine "
         f"{steps['composed']:.2f}")
-    check(hi <= bound, f"kernel vs composed logits rel_rms {hi:.3e} > "
-          f"{bound:.3e}")
+    check(hi <= LOGITS_RTOL, f"kernel vs composed logits rel_rms {hi:.3e} "
+          f"> {LOGITS_RTOL:.0e}")
+    check(planted > LOGITS_RTOL, f"planted select_k-1 fault reads rel_rms "
+          f"{planted:.3e} <= {LOGITS_RTOL:.0e}: the bound cannot see it")
+    check(bool(np.all(margin <= noise)), f"kernel and composed greedy "
+          f"tokens differ where the composed margin {margin.tolist()} "
+          f"exceeds {noise.tolist()}")
 
 
 def phase_serve(n_requests: int, seed: int):
@@ -350,7 +379,6 @@ def serve_side(label, model, params, trace, vocab, clock, mesh=None):
 
 def phase_mesh(n_requests: int, seed: int, chips: int):
     import jax
-    from jax.sharding import NamedSharding, PartitionSpec as P
     from repro.launch.mesh import make_serve_mesh
 
     check(len(jax.devices()) >= chips,
@@ -362,10 +390,9 @@ def phase_mesh(n_requests: int, seed: int, chips: int):
         f"lanes={LANES}, mesh data={chips}")
     ref, _ = serve_side("unsharded", model, params, trace, cfg.vocab_size,
                         clock)
+    # ServeLoop replicates the weights onto the mesh; chip 0 then holds
+    # them twice (~10 GB of 16), as the unsharded copy stays referenced
     mesh = make_serve_mesh(chips)
-    # replicate here and drop the one-device copy, so chip 0 does not
-    # hold the weights twice while the sharded loop runs
-    params = jax.device_put(params, NamedSharding(mesh, P()))
     toks, texts = serve_side("sharded", model, params, trace,
                              cfg.vocab_size, clock, mesh=mesh)
 

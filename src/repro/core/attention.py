@@ -215,39 +215,40 @@ def fused_auto_decision() -> dict:
 
     On TPU auto picks the fused engine: the Pallas kernels keep the
     [B,Hq,S] scores and the index tensors of the composed path in VMEM
-    and never write them to HBM. That this makes decode faster on the
-    chip is a premise — no chip benchmark has timed the two engines yet
-    (ROADMAP Queue 1). Off-TPU the kernel would lower to the XLA fallback
-    (`ref.fused_decode_ref`), which CPU profiling at ctx512 put at
-    parity-to-~6%-slower than the composed path, so auto runs the
-    composed path there."""
+    and never write them to HBM. They do NOT read only the winners' K/V
+    rows: they stream every live K/V block and attend through the winner
+    mask, so per step they read the mirror plus all live K/V, where the
+    composed path gathers only select_k rows. Whether the fused engine is
+    the faster one on the chip is therefore open until a chip benchmark
+    times the two engines (ROADMAP Queue 1 item 3); that race decides
+    whether auto keeps resolving to fused there. Off-TPU the kernel would
+    lower to the XLA fallback (`ref.fused_decode_ref`), which CPU
+    profiling at ctx512 put at parity-to-~6%-slower than the composed
+    path, so auto runs the composed path there."""
     on_tpu = jax.default_backend() == "tpu"
     return {
         "engine": "fused" if on_tpu else "composed",
         "backend": jax.default_backend(),
-        "reason": ("pallas kernels: scores and winners stay in VMEM "
-                   "(speed on the chip not yet measured)"
+        "reason": ("pallas kernels: scores and winners stay in VMEM; all "
+                   "live K/V streamed (speed vs composed not yet measured)"
                    if on_tpu else
                    "xla fallback measured at parity-to-slower vs the "
                    "composed path off-TPU"),
     }
 
 
-def _fused_enabled(prune: PruneConfig) -> bool:
-    if prune.fused == "auto":
-        return fused_auto_decision()["engine"] == "fused"
-    return bool(prune.fused)
-
-
 def decode_engine(prune: PruneConfig, latent_cache: bool = False) -> str:
-    """The unicaim decode engine `prune` resolves to outside a mesh
-    context: "fused" or "composed" (other policies: the policy name).
-    The fused engine covers the paper-default decode configuration;
-    anything it doesn't (threshold race, exact accumulation, MLA latent
-    caches) runs the composed oracle path."""
-    if prune.policy != "unicaim":
-        return prune.policy
-    if (_fused_enabled(prune) and prune.select_mode == "topk"
+    """The decode engine `prune` resolves to outside a mesh context:
+    "fused" or "composed". Only unicaim has a fused engine, and it covers
+    the paper-default decode configuration; anything it doesn't
+    (threshold race, exact accumulation, MLA latent caches) runs the
+    composed oracle path."""
+    if prune.fused == "auto":
+        enabled = fused_auto_decision()["engine"] == "fused"
+    else:
+        enabled = bool(prune.fused)
+    if (enabled and prune.policy == "unicaim"
+            and prune.select_mode == "topk"
             and prune.accumulate == "approx" and not latent_cache
             and prune.select_k % max(1, prune.select_blocks) == 0):
         return "fused"

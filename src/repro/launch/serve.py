@@ -1032,7 +1032,9 @@ class ServeLoop:
                 f"lanes={lanes} not divisible by {self.shards} shards")
             # replicate the weights once: every shard reads all of them,
             # and weights left on one device would be re-broadcast to the
-            # mesh on every dispatch
+            # mesh on every dispatch. A caller that keeps its own reference
+            # to single-device params keeps that copy as well (one more
+            # copy of the weights on that device).
             from jax.sharding import NamedSharding, PartitionSpec as P
             self.params = jax.device_put(params, NamedSharding(mesh, P()))
         self.lanes_per_shard = lanes // self.shards
@@ -2867,7 +2869,10 @@ def main(argv=None):
     else:
         prune = baselines.dense(args.prompt_len + args.new_tokens)
     model = Model(cfg, prune)
-    engine = decode_engine(prune, latent_cache=cfg.mla is not None)
+    # only unicaim has two decode engines; the summary names the one used
+    engine = ""
+    if args.policy == "unicaim":
+        engine = f" engine={decode_engine(prune, cfg.mla is not None)}"
     params = model.init(jax.random.PRNGKey(0))
     rng = np.random.default_rng(0)
 
@@ -2896,7 +2901,7 @@ def main(argv=None):
                   f"bucket={s.bucket} chunks={s.prefill_chunks} "
                   f"new={len(s.tokens)} latency={s.latency:.2f}s "
                   f"ttft={s.ttft:.2f}s occ={s.occupancy:.2f}")
-        print(f"arch={cfg.name} policy={args.policy} engine={engine} "
+        print(f"arch={cfg.name} policy={args.policy}{engine} "
               f"served {len(stats)} reqs on {args.batch} lanes in {dt:.2f}s "
               f"({agg['tokens_per_s']:.1f} tok/s, "
               f"p99_ttft={agg['p99_ttft_s']:.2f}s, "
@@ -2922,8 +2927,8 @@ def main(argv=None):
     toks = jax.block_until_ready(toks)
     dt = time.time() - t0
     mode = "loop" if args.no_scan else "scan"
-    print(f"arch={cfg.name} policy={args.policy} mode={mode} "
-          f"engine={engine} cache_slots={prune.slots} "
+    print(f"arch={cfg.name} policy={args.policy} mode={mode}{engine} "
+          f"cache_slots={prune.slots} "
           f"generated {toks.shape} in {dt:.2f}s "
           f"({args.batch * args.new_tokens / dt:.1f} tok/s)")
 

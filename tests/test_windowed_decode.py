@@ -297,7 +297,7 @@ def test_fused_auto_resolves_per_backend():
     """fused='auto' must resolve to the composed path off-TPU (the XLA
     fallback was measured at parity-to-slower) and stay a valid
     PruneConfig value."""
-    from repro.core.attention import _fused_enabled, fused_auto_decision
+    from repro.core.attention import decode_engine, fused_auto_decision
     prune = dataclasses.replace(
         baselines.unicaim(heavy=24, reserve=8, select_k=8, sink_tokens=2,
                           recent_window=4), fused="auto")
@@ -306,6 +306,6 @@ def test_fused_auto_resolves_per_backend():
     assert decision["engine"] in ("fused", "composed")
     assert decision["reason"]
     on_tpu = jax.default_backend() == "tpu"
-    assert _fused_enabled(prune) == on_tpu
-    assert _fused_enabled(dataclasses.replace(prune, fused=True))
-    assert not _fused_enabled(dataclasses.replace(prune, fused=False))
+    assert decode_engine(prune) == ("fused" if on_tpu else "composed")
+    assert decode_engine(dataclasses.replace(prune, fused=True)) == "fused"
+    assert decode_engine(dataclasses.replace(prune, fused=False)) == "composed"
