@@ -79,10 +79,14 @@ def pack_meta(mscale, kscale, vscale, valid, prot, s_pad: int) -> jax.Array:
 def cam_scores(qq, qs, mir, meta):
     """CAM mode for one slot block: [G, d] int8 · [bs, d] int8 → masked
     scores [G, bs]. The integer operands are exact in bf16 and their
-    products sum exactly in f32, so this equals the oracle's f32 einsum."""
+    products sum exactly in f32, so this equals the oracle's f32 einsum.
+    The precision is pinned: under `default_matmul_precision("highest")`
+    Mosaic would be asked for an fp32 contraction of bf16 operands, which
+    it refuses."""
     raw = jax.lax.dot_general(
         qq.astype(jnp.bfloat16), mir.astype(jnp.bfloat16),
         dimension_numbers=(((1,), (1,)), ((), ())),
+        precision=jax.lax.Precision.DEFAULT,
         preferred_element_type=jnp.float32)                # [G, bs]
     raw = raw * qs * meta[MS:MS + 1]
     return jnp.where(meta[VALID:VALID + 1] != 0, raw, NEG_INF)

@@ -402,9 +402,11 @@ def decode_block_lanes_sharded(model: Model, mesh, params, state, tok,
     cache/knob operands (asserted from the HLO in
     `tests/test_sharded_serve.py`, like the PR-7 aliasing guard).
 
-    Per-shard per-lane math is bitwise batch-size-independent (the same
-    invariant grouped admission relies on), so the sharded engine
-    streams token-identically to the unsharded one.
+    Per-shard per-lane math is the unsharded block's on fewer lanes. On
+    CPU the sharded engine streams token-identically to the unsharded
+    one (`tests/test_sharded_serve.py`); on a TPU the two sides admit in
+    different prefill groups and their greedy streams can part, so
+    `chip_smoke.py --chips 4` compares one decode step on a shared state.
     """
     from jax.sharding import PartitionSpec as P
 

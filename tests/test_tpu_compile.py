@@ -52,7 +52,7 @@ def no_persistent_cache():
     compilation_cache.reset_cache()
 
 
-def _operands(sharding, model, kv_dtype):
+def _operands(sharding, model, kv_dtype, q_dtype):
     hk, g, d = WIDTHS[model]
     bh = LANES * hk
 
@@ -62,7 +62,7 @@ def _operands(sharding, model, kv_dtype):
     kv = jnp.int8 if kv_dtype == "int8" else jnp.bfloat16
     fills = sds((bh,), jnp.int32)
     return fills, (
-        sds((bh, g, d), jnp.bfloat16),                 # q
+        sds((bh, g, d), q_dtype),                      # q
         sds((bh, g, d), jnp.int8),                     # qq
         sds((bh, g), jnp.float32),                     # qscale
         sds((bh, SLOTS, d), jnp.int8),                 # mirror
@@ -75,19 +75,31 @@ def _operands(sharding, model, kv_dtype):
         sds((bh, SLOTS, d), kv))                       # v
 
 
+# (matmul precision, query dtype): "served" as the serving path runs;
+# "compare" as chip_smoke.py compares the engines (f32 activations at
+# HIGHEST), where a kernel dot that leaves its precision to the context
+# would ask Mosaic for an fp32 contraction of bf16 operands
+MODES = {"served": ("default", jnp.bfloat16),
+         "compare": ("highest", jnp.float32)}
+
+
+@pytest.mark.parametrize("mode", sorted(MODES))
 @pytest.mark.parametrize("kv_dtype", ["bf16", "int8"])
 @pytest.mark.parametrize("model", sorted(WIDTHS))
 @pytest.mark.parametrize("kernel", ["ragged_decode", "fused_decode"])
 def test_decode_kernel_compiles_for_v5e(one_chip, no_persistent_cache,
-                                        kernel, model, kv_dtype):
-    fills, args = _operands(one_chip, model, kv_dtype)
-    if kernel == "ragged_decode":
-        fn = jax.jit(lambda f, *a: ragged_decode(f, *a, select_k=SELECT_K))
-        lowered = fn.lower(fills, *args)
-    else:
-        # two selection blocks: the block-local race of select_blocks=2
-        fn = jax.jit(lambda *a: fused_decode(*a, select_k=SELECT_K,
-                                             num_blocks=2))
-        lowered = fn.lower(*args)
-    hlo = lowered.compile().as_text()
+                                        kernel, model, kv_dtype, mode):
+    precision, q_dtype = MODES[mode]
+    fills, args = _operands(one_chip, model, kv_dtype, q_dtype)
+    with jax.default_matmul_precision(precision):
+        if kernel == "ragged_decode":
+            fn = jax.jit(lambda f, *a: ragged_decode(f, *a,
+                                                     select_k=SELECT_K))
+            lowered = fn.lower(fills, *args)
+        else:
+            # two selection blocks: the block-local race of select_blocks=2
+            fn = jax.jit(lambda *a: fused_decode(*a, select_k=SELECT_K,
+                                                 num_blocks=2))
+            lowered = fn.lower(*args)
+        hlo = lowered.compile().as_text()
     assert "tpu_custom_call" in hlo, f"{kernel} did not lower to Mosaic"
