@@ -51,8 +51,10 @@ bit-identical to prefilling the whole prompt from scratch.
 from __future__ import annotations
 
 import argparse
+import contextlib
 import dataclasses
 import functools
+import itertools
 import math
 import time
 import warnings
@@ -67,6 +69,7 @@ from repro.configs.base import get_config, reduced
 from repro.core import baselines
 from repro.core.attention import decode_engine
 from repro.launch.prefix_cache import PrefixCache, RowsEntry, StateEntry
+from repro.launch.spans import add as add_span, span
 from repro.models.transformer import Model
 from repro.surgery import (cache_prefix_rows, state_lane_insert,
                            state_lane_select, state_lane_slice,
@@ -510,6 +513,9 @@ def _lanes_block_fn(key, steps: int, window: Optional[int] = None,
     else:
         fn = functools.partial(decode_block_lanes_sharded, model, mesh,
                                steps=steps, window=window)
+    # jit names the program after the function; a bare partial traces as
+    # `jit__unknown`
+    fn.__name__ = fn.func.__name__
     return jax.jit(fn, donate_argnums=_donate_argnums(1, 2, 3, 4, 6))
 
 
@@ -993,6 +999,16 @@ class ServeLoop:
     pruning; `t_admit`/ttft cover the whole sliced prefill. Requires
     `model.supports_chunked_prefill()` (plain attention stacks); others
     fall back to whole-bucket admission.
+
+    **Spans** (`launch/spans.py`). Each round records `serve.round`, with
+    `serve.sweep`, `serve.schedule` (a `serve.admit` per admission),
+    `serve.chunk` (a prefill slice) and `serve.block` (its
+    `serve.block.launch`) inside, and a `serve.wait` around each
+    blocking device->host read; each resolved request records
+    `serve.request.queue` and `serve.request.first_token`. They go to
+    the process-wide ring `spans.RECORDER`, and into the profiler trace
+    while a profiler session runs. The `serve.block` record is the public
+    per-block record (lanes, tokens, window, per-lane progress).
     """
 
     def __init__(self, model: Model, params, lanes: int,
@@ -1123,6 +1139,9 @@ class ServeLoop:
         self._eos_lens: List[int] = []
         self._budget_done = 0
         self._finished: set = set()           # rids with t_done recorded
+        # rid -> perf_counter start of the admission that took the request
+        # to its first token: where its serve.request.queue span ends
+        self._admit_t: Dict[int, float] = {}
         self.prefix_cache: Optional[PrefixCache] = (
             PrefixCache(prefix_cache_bytes) if prefix_cache_bytes > 0
             else None)
@@ -1149,9 +1168,7 @@ class ServeLoop:
             "donation": donation_mode(),
             "prefix_lookups": 0, "prefix_hits": 0,
             "prefix_exact_hits": 0, "prefix_copies": 0,
-            "prefix_tokens_reused": 0,
-            "prefix_inserts": 0, "prefix_evictions": 0,
-            "preempt_cache_inserts": 0,
+            "prefix_tokens_reused": 0, "preempt_cache_inserts": 0,
         }
         # per-(priority, bucket) EOS-length samples — drain prediction
         # uses a class-local mean once a class has >= 4 EOS completions,
@@ -1196,7 +1213,7 @@ class ServeLoop:
     # -- time ----------------------------------------------------------------
 
     def _now(self) -> float:
-        return 0.0 if self._t0 is None else time.monotonic() - self._t0
+        return 0.0 if self._t0 is None else time.perf_counter() - self._t0
 
     # -- request intake ------------------------------------------------------
 
@@ -1317,6 +1334,7 @@ class ServeLoop:
         if st.t_first < st.t_admit:
             st.t_first = st.t_done
         req.admitted = True                    # lazy-prune marker
+        self._record_request(st)
         self.completed.append(st)
         self.done.append(st.tokens)
         self._finished.add(req.rid)
@@ -1414,24 +1432,25 @@ class ServeLoop:
         in-device mask drops their writes — no recompile) and finish the
         lane with partial tokens. Runs every scheduler round, so an
         expired lane frees within one decode block."""
-        for lane in np.flatnonzero(self.active):
-            lane = int(lane)
-            rid = self._lane_rid[lane]
-            req = self._req_by_rid.get(rid) if rid is not None else None
-            if req is None:                    # legacy admit() batch
-                continue
-            if req.cancelled:
-                self.counters["cancelled_requests"] += 1
-                outcome, detail = "cancelled", ""
-            elif self._deadline_over(req, now):
-                self.counters["deadline_expired"] += 1
-                outcome = "deadline"
-                detail = f"deadline_s={req.deadline_s} expired mid-decode"
-            else:
-                continue
-            self.active[lane] = False
-            self.remaining[lane] = 0
-            self._finish_lane(lane, now, outcome=outcome, detail=detail)
+        with span("serve.sweep"):
+            for lane in np.flatnonzero(self.active):
+                lane = int(lane)
+                rid = self._lane_rid[lane]
+                req = self._req_by_rid.get(rid) if rid is not None else None
+                if req is None:                    # legacy admit() batch
+                    continue
+                if req.cancelled:
+                    self.counters["cancelled_requests"] += 1
+                    outcome, detail = "cancelled", ""
+                elif self._deadline_over(req, now):
+                    self.counters["deadline_expired"] += 1
+                    outcome = "deadline"
+                    detail = f"deadline_s={req.deadline_s} expired mid-decode"
+                else:
+                    continue
+                self.active[lane] = False
+                self.remaining[lane] = 0
+                self._finish_lane(lane, now, outcome=outcome, detail=detail)
 
     def _qkey(self, req: Request) -> Tuple[int, int]:
         """Scheduling-class deque key: sorts as (-priority, bucket)."""
@@ -1564,27 +1583,42 @@ class ServeLoop:
         grid = None if self.buckets == "auto" else self.buckets
         return bucket_length(len(req.prompt), grid)
 
+    @contextlib.contextmanager
+    def _admitting(self, kind: str, group: List[Request], bucket: int):
+        """The `serve.admit` span of one admission; its start is where
+        each member's queue wait ends (a resumed request that has already
+        emitted keeps the admission that gave it its first token)."""
+        rid = group[0].rid if len(group) == 1 else None
+        with span("serve.admit", rid=rid, kind=kind, bucket=bucket,
+                  group=len(group)) as rec:
+            for r in group:
+                if r.resume is None or not r.resume.outputs:
+                    self._admit_t[r.rid] = rec.t0
+            yield
+
     def _admit_lane(self, lane: int, req: Request):
         """Prefill one request (whole-bucket) and splice it into `lane`.
         Consults the prefix cache for an exact-prompt hit first, and
         inserts the finished prefill back as a donor."""
-        self._ensure_state()
-        hit, _ = self._cache_match(req, rows_cap=None)
-        if hit is not None:
-            self._splice_cached(lane, req, hit)
-            return
-        padded, bucket = self._padded_prompt(req)
-        if bucket == len(req.prompt) and self.buckets is None:
-            self._prefill_shapes.add(("exact", bucket))
-            logits, fresh = self._prefill_one(self.params, jnp.asarray(padded))
-        else:
-            self._prefill_shapes.add(("bucket", bucket))
-            logits, fresh = self._prefill_one(
-                self.params, jnp.asarray(padded),
-                jnp.asarray(len(req.prompt), jnp.int32))
-        self.counters["prefill_dispatches"] += 1
-        self._splice(lane, req, logits, fresh, bucket=bucket)
-        self._cache_insert_finalized(req, logits, fresh, bucket)
+        with self._admitting("lane", [req], req.bucket):
+            self._ensure_state()
+            hit, _ = self._cache_match(req, rows_cap=None)
+            if hit is not None:
+                self._splice_cached(lane, req, hit)
+                return
+            padded, bucket = self._padded_prompt(req)
+            if bucket == len(req.prompt) and self.buckets is None:
+                self._prefill_shapes.add(("exact", bucket))
+                logits, fresh = self._prefill_one(self.params,
+                                                  jnp.asarray(padded))
+            else:
+                self._prefill_shapes.add(("bucket", bucket))
+                logits, fresh = self._prefill_one(
+                    self.params, jnp.asarray(padded),
+                    jnp.asarray(len(req.prompt), jnp.int32))
+            self.counters["prefill_dispatches"] += 1
+            self._splice(lane, req, logits, fresh, bucket=bucket)
+            self._cache_insert_finalized(req, logits, fresh, bucket)
 
     def _sample_key(self):
         """Fresh subkey for an admission seed when sampling; when greedy
@@ -1640,7 +1674,8 @@ class ServeLoop:
         self.counters["admit_dispatches"] += 1
         self._register_admit(lane, req, bucket=bucket,
                              prefill_chunks=prefill_chunks,
-                             prefix_tokens=prefix_tokens, lane_key=carry)
+                             prefix_tokens=prefix_tokens,
+                             lane_key=self._host("seed", np.asarray, carry))
 
     # -- prefix cache --------------------------------------------------------
 
@@ -1683,12 +1718,14 @@ class ServeLoop:
         self.counters["prefix_tokens_reused"] += entry.length
         self._register_admit(lane, req, bucket=entry.bucket,
                              prefill_chunks=0, prefix_tokens=entry.length,
-                             prefix_exact=True, lane_key=carry)
+                             prefix_exact=True,
+                             lane_key=self._host("seed", np.asarray, carry))
 
-    def _sync_cache_counters(self):
-        pc = self.prefix_cache
-        self.counters["prefix_inserts"] = pc.inserts
-        self.counters["prefix_evictions"] = pc.evictions
+    def _host(self, what: str, read, *args):
+        """`read(*args)`, a blocking device->host read, under a
+        `serve.wait` span that names `what` is read."""
+        with span("serve.wait", what=what):
+            return read(*args)
 
     def _cache_insert_finalized(self, req: Request, logits, fresh,
                                 bucket: int):
@@ -1702,10 +1739,11 @@ class ServeLoop:
         pc = self.prefix_cache
         if pc is None or not req.reuse_prefix:
             return
-        host_state = jax.tree.map(np.asarray, fresh)
+        logits, host_state = self._host("cache", jax.device_get,
+                                        (logits, fresh))
         pc.insert_state(req.prompt, StateEntry(
             length=len(req.prompt), bucket=bucket,
-            logits=np.asarray(logits), state=host_state))
+            logits=logits, state=host_state))
         c = self.chunk_prefill
         n = len(req.prompt)
         if (self._rows_reuse and n % c == 0
@@ -1714,7 +1752,6 @@ class ServeLoop:
             rows = cache_prefix_rows(host_state.kv, n)
             if rows is not None:
                 pc.insert_rows(req.prompt, RowsEntry(n, *rows))
-        self._sync_cache_counters()
 
     def _admit_group(self, lanes: List[int], group: List[Request]):
         """Admit G same-bucket requests with ONE batched prefill dispatch
@@ -1725,56 +1762,59 @@ class ServeLoop:
         wide-lane engines don't pay a full lanes-row prefill; the
         splice's source map drops the surplus rows. Bit-identical to
         admitting the same requests sequentially via `_admit_lane`."""
-        self._ensure_state()
-        padded = [self._padded_prompt(r)[0] for r in group]
-        bucket = len(padded[0])
-        g = len(group)
-        gp = min(1 << (g - 1).bit_length(), self.lanes)      # pow2 rows
-        rows = np.stack(padded)                              # [G, W]
-        lengths = np.fromiter((len(r.prompt) for r in group), np.int32, g)
-        if g < gp:
-            pad_rows = np.broadcast_to(rows[:1], (gp - g, bucket))
-            rows = np.concatenate([rows, pad_rows], axis=0)
-            lengths = np.concatenate(
-                [lengths, np.full(gp - g, lengths[0], np.int32)])
-        src = np.full(self.lanes, -1, np.int32)
-        for i, lane in enumerate(lanes):
-            src[lane] = i
-        if self.buckets is None:               # exact-width group
-            self._prefill_shapes.add(("group-exact", bucket, gp))
-            logits, fresh = self._prefill_group(self.params,
-                                                jnp.asarray(rows))
-        else:
-            self._prefill_shapes.add(("group", bucket, gp))
-            logits, fresh = self._prefill_group(self.params,
-                                                jnp.asarray(rows),
-                                                jnp.asarray(lengths))
-        self.counters["prefill_dispatches"] += 1
-        # per-row seeding: each request draws from its OWN stream and
-        # gets its own lane PRNG carry (pad rows mirror row 0 — their
-        # draws are dropped by the splice's source map anyway)
-        t_arr = np.empty(gp, np.float32)
-        k_arr = np.empty(gp, np.int32)
-        p_arr = np.empty(gp, np.float32)
-        draws = np.empty((gp, 2), np.uint32)
-        carries: List[np.ndarray] = []
-        for i, r in enumerate(group):
-            t_arr[i], k_arr[i], p_arr[i] = self._req_sampling(r)
-            draw, carry = self._seed_keys(r)
-            draws[i] = np.asarray(draw, np.uint32)
-            carries.append(np.asarray(carry, np.uint32))
-        t_arr[g:], k_arr[g:], p_arr[g:] = t_arr[0], k_arr[0], p_arr[0]
-        draws[g:] = draws[0]
-        self.state, self.tok = _admit_group_fn()(
-            self.state, self.tok, jnp.asarray(src), fresh, logits,
-            jnp.asarray(draws), jnp.asarray(t_arr), jnp.asarray(k_arr),
-            jnp.asarray(p_arr))
-        self.counters["admit_dispatches"] += 1
-        self.counters["grouped_admissions"] += 1
-        self.counters["grouped_requests"] += g
-        for lane, req, carry in zip(lanes, group, carries):
-            self._register_admit(lane, req, bucket=bucket, group_size=g,
-                                 lane_key=carry)
+        with self._admitting("group", group, group[0].bucket):
+            self._ensure_state()
+            padded = [self._padded_prompt(r)[0] for r in group]
+            bucket = len(padded[0])
+            g = len(group)
+            gp = min(1 << (g - 1).bit_length(), self.lanes)  # pow2 rows
+            rows = np.stack(padded)                          # [G, W]
+            lengths = np.fromiter((len(r.prompt) for r in group), np.int32,
+                                  g)
+            if g < gp:
+                pad_rows = np.broadcast_to(rows[:1], (gp - g, bucket))
+                rows = np.concatenate([rows, pad_rows], axis=0)
+                lengths = np.concatenate(
+                    [lengths, np.full(gp - g, lengths[0], np.int32)])
+            src = np.full(self.lanes, -1, np.int32)
+            for i, lane in enumerate(lanes):
+                src[lane] = i
+            if self.buckets is None:               # exact-width group
+                self._prefill_shapes.add(("group-exact", bucket, gp))
+                logits, fresh = self._prefill_group(self.params,
+                                                    jnp.asarray(rows))
+            else:
+                self._prefill_shapes.add(("group", bucket, gp))
+                logits, fresh = self._prefill_group(self.params,
+                                                    jnp.asarray(rows),
+                                                    jnp.asarray(lengths))
+            self.counters["prefill_dispatches"] += 1
+            # per-row seeding: each request draws from its OWN stream and
+            # gets its own lane PRNG carry (pad rows mirror row 0 — their
+            # draws are dropped by the splice's source map anyway)
+            t_arr = np.empty(gp, np.float32)
+            k_arr = np.empty(gp, np.int32)
+            p_arr = np.empty(gp, np.float32)
+            draws = np.empty((gp, 2), np.uint32)
+            carries: List[np.ndarray] = []
+            keys = self._host("seed", jax.device_get,
+                              [self._seed_keys(r) for r in group])
+            for i, (r, (draw, carry)) in enumerate(zip(group, keys)):
+                t_arr[i], k_arr[i], p_arr[i] = self._req_sampling(r)
+                draws[i] = np.asarray(draw, np.uint32)
+                carries.append(np.asarray(carry, np.uint32))
+            t_arr[g:], k_arr[g:], p_arr[g:] = t_arr[0], k_arr[0], p_arr[0]
+            draws[g:] = draws[0]
+            self.state, self.tok = _admit_group_fn()(
+                self.state, self.tok, jnp.asarray(src), fresh, logits,
+                jnp.asarray(draws), jnp.asarray(t_arr), jnp.asarray(k_arr),
+                jnp.asarray(p_arr))
+            self.counters["admit_dispatches"] += 1
+            self.counters["grouped_admissions"] += 1
+            self.counters["grouped_requests"] += g
+            for lane, req, carry in zip(lanes, group, carries):
+                self._register_admit(lane, req, bucket=bucket, group_size=g,
+                                     lane_key=carry)
 
     def _set_lane_knobs(self, lane: int, req: Request) -> None:
         """Load one lane's runtime knob slots from the request (its
@@ -1835,23 +1875,24 @@ class ServeLoop:
         lane — zero prefill work; the stream continues exactly where it
         stopped (outputs, budget, PRNG carry, and the carried next token
         all restored)."""
-        self._ensure_state()
-        rs = req.resume
-        req.resume = None
-        self.state, self.tok = _resume_fn()(
-            self.state, self.tok, lane, rs.state,
-            jnp.asarray(rs.tok, jnp.int32))
-        self.counters["admit_dispatches"] += 1
-        self.active[lane] = rs.rem > 0
-        self.remaining[lane] = rs.rem
-        self.outputs[lane] = list(rs.outputs)
-        self._lane_rid[lane] = req.rid
-        self._set_lane_knobs(lane, req)
-        self._lane_keys[lane] = np.asarray(rs.key, np.uint32)
-        st = self.stats[req.rid]
-        st.lane = lane
-        st.admit_seq = self._admit_seq
-        self._admit_seq += 1
+        with self._admitting("resume", [req], req.bucket):
+            self._ensure_state()
+            rs = req.resume
+            req.resume = None
+            self.state, self.tok = _resume_fn()(
+                self.state, self.tok, lane, rs.state,
+                jnp.asarray(rs.tok, jnp.int32))
+            self.counters["admit_dispatches"] += 1
+            self.active[lane] = rs.rem > 0
+            self.remaining[lane] = rs.rem
+            self.outputs[lane] = list(rs.outputs)
+            self._lane_rid[lane] = req.rid
+            self._set_lane_knobs(lane, req)
+            self._lane_keys[lane] = np.asarray(rs.key, np.uint32)
+            st = self.stats[req.rid]
+            st.lane = lane
+            st.admit_seq = self._admit_seq
+            self._admit_seq += 1
 
     def _preempt_lane(self, lane: int) -> None:
         """Evict one active lane for a higher class: capture its exact
@@ -1862,7 +1903,8 @@ class ServeLoop:
         req = self._req_by_rid[rid]
         fresh = _lane_slice_fn(_model_key(self.model))(self.state, lane)
         req.resume = _ResumeState(
-            state=fresh, tok=int(np.asarray(self.tok)[lane]),
+            state=fresh,
+            tok=int(self._host("tok", np.asarray, self.tok)[lane]),
             rem=int(self.remaining[lane]),
             key=self._lane_keys[lane].copy(),
             outputs=list(self.outputs[lane]))
@@ -1904,11 +1946,10 @@ class ServeLoop:
         # cache_prefix_rows checks alignment on the light fields
         # (fill/step/pos/valid) before pulling k/v/acc to host, so a
         # refused donor costs no heavy device->host copy
-        rows = cache_prefix_rows(kv, n)
+        rows = self._host("cache", cache_prefix_rows, kv, n)
         if rows is not None:
             pc.insert_rows(req.prompt, RowsEntry(n, *rows))
             self.counters["preempt_cache_inserts"] += 1
-        self._sync_cache_counters()
 
     def _requeue(self, req: Request) -> None:
         """Re-insert a preempted request at its arrival rank: it resumes
@@ -2094,33 +2135,34 @@ class ServeLoop:
         hit at depth p pre-fills the workspace with the cached rows and
         resumes at chunk p/C — the remaining slices repeat the
         from-scratch accumulation bit-for-bit."""
-        self._ensure_state()
-        c = self.chunk_prefill
-        # deepest usable donor boundary: the final chunk (the one holding
-        # the last real token, whose hidden feeds the logits) always runs
-        cap = ((len(req.prompt) - 1) // c) * c
-        hit, rows = self._cache_match(req, rows_cap=cap)
-        if hit is not None:
-            self._splice_cached(lane, req, hit)
-            return
-        ws = math.ceil(bucket / c) * c
-        if ws != bucket:
-            ext = np.zeros(ws, padded.dtype)
-            ext[:len(padded)] = padded
-            padded = ext
-        if rows is not None:
-            pstate = self._resume(rows.k, rows.v, rows.acc, ws)
-            base = rows.depth
-            self.counters["prefix_copies"] += 1
-            self.counters["prefix_tokens_reused"] += base
-        else:
-            pstate = self.model.init_prefill_chunk_state(1, ws)
-            base = 0
-        self._pending = _ChunkedPrefill(
-            req=req, lane=lane, bucket=ws, padded=padded, pstate=pstate,
-            n_chunks=math.ceil(len(req.prompt) / c), next_chunk=base // c,
-            base=base, collect=(self._rows_reuse and req.reuse_prefix))
-        self._prefill_shapes.add(("chunk", c, ws))
+        with self._admitting("chunked", [req], bucket):
+            self._ensure_state()
+            c = self.chunk_prefill
+            # deepest usable donor boundary: the final chunk (the one holding
+            # the last real token, whose hidden feeds the logits) always runs
+            cap = ((len(req.prompt) - 1) // c) * c
+            hit, rows = self._cache_match(req, rows_cap=cap)
+            if hit is not None:
+                self._splice_cached(lane, req, hit)
+                return
+            ws = math.ceil(bucket / c) * c
+            if ws != bucket:
+                ext = np.zeros(ws, padded.dtype)
+                ext[:len(padded)] = padded
+                padded = ext
+            if rows is not None:
+                pstate = self._resume(rows.k, rows.v, rows.acc, ws)
+                base = rows.depth
+                self.counters["prefix_copies"] += 1
+                self.counters["prefix_tokens_reused"] += base
+            else:
+                pstate = self.model.init_prefill_chunk_state(1, ws)
+                base = 0
+            self._pending = _ChunkedPrefill(
+                req=req, lane=lane, bucket=ws, padded=padded, pstate=pstate,
+                n_chunks=math.ceil(len(req.prompt) / c), next_chunk=base // c,
+                base=base, collect=(self._rows_reuse and req.reuse_prefix))
+            self._prefill_shapes.add(("chunk", c, ws))
 
     def _advance_chunked(self) -> bool:
         """Run ONE prefill slice of the in-flight chunked admission (the
@@ -2135,43 +2177,46 @@ class ServeLoop:
             self._pending = None
             self._resolve_dead(p.req)
             return False
-        c = self.chunk_prefill
-        ci = p.next_chunk
-        tok_c = jnp.asarray(p.padded[ci * c:(ci + 1) * c][None])
-        length = jnp.asarray([len(p.req.prompt)], jnp.int32)
-        p.x_last, p.pstate = self._chunk(self.params, p.pstate, tok_c,
-                                         jnp.asarray(ci * c, jnp.int32),
-                                         length)
-        self.counters["chunk_dispatches"] += 1
-        p.next_chunk += 1
-        q = p.next_chunk * c
-        if p.collect and p.base < q <= (len(p.req.prompt) // c) * c:
-            # host snapshot of the acc prefix at boundary q: acc columns
-            # [0, q) depend only on tokens [0, q) (columns past a chunk's
-            # causal reach carry exactly-zero mass), so together with the
-            # write-once K/V rows this is a bit-exact resume donor for
-            # ANY continuation sharing those tokens. Boundaries whose
-            # chunk holds pad tokens (q > prompt length) are never taken.
-            p.snap_acc.append((q, np.asarray(p.pstate.acc[:, 0, :, :q])))
-        if p.next_chunk >= p.n_chunks:
-            rows_kv = None
-            if p.snap_acc:
-                # ONE workspace K/V snapshot covers every boundary (rows
-                # are write-once) — taken before finalize donates pstate
-                q_max = p.snap_acc[-1][0]
-                rows_kv = (np.asarray(p.pstate.k[:, 0, :, :q_max]),
-                           np.asarray(p.pstate.v[:, 0, :, :q_max]))
-            logits, fresh = self._finalize(
-                self.params, p.pstate, p.x_last,
-                jnp.asarray((p.n_chunks - 1) * c, jnp.int32), length)
-            self.counters["prefill_dispatches"] += 1
-            self._pending = None
-            self._splice(p.lane, p.req, logits[0], fresh, bucket=p.bucket,
-                         prefill_chunks=p.n_chunks, prefix_tokens=p.base)
-            # trie insertion AFTER the splice: admission latency (ttft)
-            # never pays for the host copies; fresh/logits survive the
-            # splice (only state/tok are donated)
-            self._cache_insert_chunked(p, logits[0], fresh, rows_kv)
+        with span("serve.chunk", rid=p.req.rid):
+            c = self.chunk_prefill
+            ci = p.next_chunk
+            tok_c = jnp.asarray(p.padded[ci * c:(ci + 1) * c][None])
+            length = jnp.asarray([len(p.req.prompt)], jnp.int32)
+            p.x_last, p.pstate = self._chunk(self.params, p.pstate, tok_c,
+                                             jnp.asarray(ci * c, jnp.int32),
+                                             length)
+            self.counters["chunk_dispatches"] += 1
+            p.next_chunk += 1
+            q = p.next_chunk * c
+            if p.collect and p.base < q <= (len(p.req.prompt) // c) * c:
+                # host snapshot of the acc prefix at boundary q: acc columns
+                # [0, q) depend only on tokens [0, q) (columns past a chunk's
+                # causal reach carry exactly-zero mass), so together with the
+                # write-once K/V rows this is a bit-exact resume donor for
+                # ANY continuation sharing those tokens. Boundaries whose
+                # chunk holds pad tokens (q > prompt length) are never taken.
+                p.snap_acc.append((q, self._host(
+                    "cache", np.asarray, p.pstate.acc[:, 0, :, :q])))
+            if p.next_chunk >= p.n_chunks:
+                rows_kv = None
+                if p.snap_acc:
+                    # ONE workspace K/V snapshot covers every boundary (rows
+                    # are write-once) — taken before finalize donates pstate
+                    q_max = p.snap_acc[-1][0]
+                    rows_kv = self._host("cache", jax.device_get, (
+                        p.pstate.k[:, 0, :, :q_max],
+                        p.pstate.v[:, 0, :, :q_max]))
+                logits, fresh = self._finalize(
+                    self.params, p.pstate, p.x_last,
+                    jnp.asarray((p.n_chunks - 1) * c, jnp.int32), length)
+                self.counters["prefill_dispatches"] += 1
+                self._pending = None
+                self._splice(p.lane, p.req, logits[0], fresh, bucket=p.bucket,
+                             prefill_chunks=p.n_chunks, prefix_tokens=p.base)
+                # trie insertion AFTER the splice: admission latency (ttft)
+                # never pays for the host copies; fresh/logits survive the
+                # splice (only state/tok are donated)
+                self._cache_insert_chunked(p, logits[0], fresh, rows_kv)
         return True
 
     def _cache_insert_chunked(self, p: _ChunkedPrefill, logits, fresh,
@@ -2185,16 +2230,16 @@ class ServeLoop:
         if pc is None or not p.req.reuse_prefix:
             return
         tokens = np.asarray(p.req.prompt)
+        logits, fresh = self._host("cache", jax.device_get, (logits, fresh))
         pc.insert_state(tokens, StateEntry(
-            length=len(tokens), bucket=p.bucket, logits=np.asarray(logits),
-            state=jax.tree.map(np.asarray, fresh)))
+            length=len(tokens), bucket=p.bucket, logits=logits,
+            state=fresh))
         if rows_kv is not None:
             k_all, v_all = rows_kv                     # [L, Hk, q_max, dh]
             for q, acc_q in p.snap_acc:
                 pc.insert_rows(tokens[:q], RowsEntry(
                     q, k_all[:, :, :q].copy(), v_all[:, :, :q].copy(),
                     acc_q))
-        self._sync_cache_counters()
 
     def schedule(self) -> int:
         """Admit queued, already-arrived requests into free lanes.
@@ -2243,88 +2288,91 @@ class ServeLoop:
         the lane that was freed for it. A 1-shard engine reduces exactly
         to the unsharded free-lane list.
         """
-        n = 0
-        while True:
-            self._drain_arrivals(self._now())
-            if self._arrived_count == 0 and not self._reserved:
-                break
-            free = max(self.shard_free_lanes(), key=len)
-            if not free:
-                if self._try_preempt():
-                    continue
-                self._reserve()
-                break
-            if self._reserved:
-                group = self._take_reserved(len(free))
-                self.counters["reserved_admits"] += len(group)
-                n += self._admit_chosen(free, group)
-                continue
-            fifo_head = self._fifo_head()      # arrived_count > 0 ⇒ set
-            if not self.group_admit:
-                target, take = self._qkey(fifo_head), 1
-            else:
-                best = min(self._bucket_q)     # best class, shortest bucket
-                if self._arrived_count > len(free):
-                    target = best
-                    if (-best[0] <= fifo_head.priority
-                            and target != self._qkey(fifo_head)
-                            and self._head_skips >= self.max_head_skips):
-                        target = self._qkey(fifo_head)  # aging kicks in
-                else:                          # off load: FIFO head, unless
-                    target = self._qkey(fifo_head)      # a class outranks it
-                    if -best[0] > fifo_head.priority:
-                        target = best
-                take = len(free)
-            if self._bucket_q[target][0].resume is not None:
-                # preempted request resuming: zero-prefill solo splice
-                req = self._take_bucket(target, 1)[0]
-                if self._resolve_dead(req):
-                    continue
-                self._head_skips = (0 if fifo_head is req
-                                    else self._head_skips + 1)
-                self._admit_resumed(free[0], req)
-                n += 1
-                continue
-            if (self.group_admit and self._pending is not None
-                    and self._needs_chunking(target[1])):
-                # one sliced prefill at a time — instead of idling the
-                # free lanes behind it, admit the shortest chunk-free
-                # bucket this round (resume heads are chunk-free by
-                # construction); the head's aging credit is NOT touched
-                # on a blocked round, so the max_head_skips bound keeps
-                # holding
-                alts = [k for k in self._bucket_q
-                        if not self._needs_chunking(k[1])
-                        or self._bucket_q[k][0].resume is not None]
-                if not alts:
+        with span("serve.schedule") as rec:
+            n = 0
+            while True:
+                self._drain_arrivals(self._now())
+                if self._arrived_count == 0 and not self._reserved:
                     break
-                target = min(alts)
+                free = max(self.shard_free_lanes(), key=len)
+                if not free:
+                    if self._try_preempt():
+                        continue
+                    self._reserve()
+                    break
+                if self._reserved:
+                    group = self._take_reserved(len(free))
+                    self.counters["reserved_admits"] += len(group)
+                    n += self._admit_chosen(free, group)
+                    continue
+                fifo_head = self._fifo_head()      # arrived_count > 0 ⇒ set
+                if not self.group_admit:
+                    target, take = self._qkey(fifo_head), 1
+                else:
+                    best = min(self._bucket_q)  # best class, shortest bucket
+                    if self._arrived_count > len(free):
+                        target = best
+                        if (-best[0] <= fifo_head.priority
+                                and target != self._qkey(fifo_head)
+                                and self._head_skips >= self.max_head_skips):
+                            target = self._qkey(fifo_head)  # aging kicks in
+                    else:                       # off load: FIFO head, unless
+                        target = self._qkey(fifo_head)  # a class outranks it
+                        if -best[0] > fifo_head.priority:
+                            target = best
+                    take = len(free)
                 if self._bucket_q[target][0].resume is not None:
+                    # preempted request resuming: zero-prefill solo splice
                     req = self._take_bucket(target, 1)[0]
                     if self._resolve_dead(req):
                         continue
+                    self._head_skips = (0 if fifo_head is req
+                                        else self._head_skips + 1)
                     self._admit_resumed(free[0], req)
                     n += 1
                     continue
-            if self._needs_chunking(target[1]):
-                if self._pending is not None:
-                    break                      # one sliced prefill at a time
-                # aging accounting: `is`/`in` are identity comparisons
-                # (Request eq=False); only rounds that ADMIT something
-                # consume or earn credit
-                head = self._take_bucket(target, 1)[0]
-                if self._resolve_dead(head):
+                if (self.group_admit and self._pending is not None
+                        and self._needs_chunking(target[1])):
+                    # one sliced prefill at a time — instead of idling the
+                    # free lanes behind it, admit the shortest chunk-free
+                    # bucket this round (resume heads are chunk-free by
+                    # construction); the head's aging credit is NOT touched
+                    # on a blocked round, so the max_head_skips bound keeps
+                    # holding
+                    alts = [k for k in self._bucket_q
+                            if not self._needs_chunking(k[1])
+                            or self._bucket_q[k][0].resume is not None]
+                    if not alts:
+                        break
+                    target = min(alts)
+                    if self._bucket_q[target][0].resume is not None:
+                        req = self._take_bucket(target, 1)[0]
+                        if self._resolve_dead(req):
+                            continue
+                        self._admit_resumed(free[0], req)
+                        n += 1
+                        continue
+                if self._needs_chunking(target[1]):
+                    if self._pending is not None:
+                        break                   # one sliced prefill at a time
+                    # aging accounting: `is`/`in` are identity comparisons
+                    # (Request eq=False); only rounds that ADMIT something
+                    # consume or earn credit
+                    head = self._take_bucket(target, 1)[0]
+                    if self._resolve_dead(head):
+                        continue
+                    self._head_skips = (0 if fifo_head is head
+                                        else self._head_skips + 1)
+                    self._start_chunked(free[0], head,
+                                        self._padded_prompt(head)[0],
+                                        head.bucket)
                     continue
-                self._head_skips = (0 if fifo_head is head
+                group = self._take_bucket(target, take)
+                self._head_skips = (0 if fifo_head in group
                                     else self._head_skips + 1)
-                self._start_chunked(free[0], head,
-                                    self._padded_prompt(head)[0],
-                                    head.bucket)
-                continue
-            group = self._take_bucket(target, take)
-            self._head_skips = (0 if fifo_head in group
-                                else self._head_skips + 1)
-            n += self._admit_chosen(free, group)
+                n += self._admit_chosen(free, group)
+            rec.attrs.update(admitted=n, waiting=self._arrived_count
+                             + len(self._reserved))
         return n
 
     def _admit_chosen(self, free: List[int], group: List[Request]) -> int:
@@ -2394,7 +2442,7 @@ class ServeLoop:
             "request and drive with run()",
             DeprecationWarning, stacklevel=2)
         if self._t0 is None:
-            self._t0 = time.monotonic()
+            self._t0 = time.perf_counter()
         batch = {"tokens": jnp.asarray(prompts)}
         logits, self.state = self._prefill(self.params, batch)
         self.counters["prefill_dispatches"] += 1
@@ -2424,6 +2472,7 @@ class ServeLoop:
             rid = self._next_rid
             self._next_rid += 1
             self._lane_rid[lane] = rid
+            self._admit_t[rid] = self._t0 + now
             self.stats[rid] = RequestStats(
                 rid, prompts.shape[1], self.max_new, lane=lane,
                 t_arrival=now, t_admit=now, bucket=prompts.shape[1])
@@ -2448,7 +2497,7 @@ class ServeLoop:
                 or self.state.kv is None or not self.active.any():
             return None
         from repro.core.cache import decode_window
-        fill = np.asarray(self.state.kv.fill)          # [L, lanes]
+        fill = self._host("fill", np.asarray, self.state.kv.fill)  # [L, B]
         max_fill = int(fill[:, self.active].max())
         return decode_window(max_fill, steps, self.model.decode_slots,
                              self.model.prune, grid=self.window_grid)
@@ -2480,12 +2529,31 @@ class ServeLoop:
         steps = steps or self._effective_block()
         if self.state is None or not self.active.any():
             return bool(self.active.any())
+        with span("serve.block", steps=steps,
+                  lanes=int(self.active.sum()),
+                  waiting=self._arrived_waiting()) as rec:
+            return self._dispatch_block(steps, rec)
+
+    def _arrived_waiting(self) -> int:
+        """Requests that have arrived and wait for a lane, drained into
+        the queues or not."""
+        now = self._now()
+        due = sum(1 for _ in itertools.takewhile(
+            lambda r: r.arrival <= now, self._arrivals))
+        return self._arrived_count + len(self._reserved) + due
+
+    def _dispatch_block(self, steps: int, rec) -> bool:
+        """`_step_block`'s dispatch and host accounting; fills in its
+        `serve.block` record `rec`."""
         window = self._decode_window(steps)
         self._windows.add(window)
         self.counters["decode_windows"] = len(self._windows)
         fn = _lanes_block_fn(_model_key(self.model), steps, window,
                              self.mesh)
         was_active = self.active.copy()
+        lanes = [(int(i), self._lane_rid[i]) for i in np.flatnonzero(
+            was_active) if self._lane_rid[i] is not None]
+        before = [len(self.outputs[i]) for i, _ in lanes]
         blk = self.counters["decode_blocks"]
         if self.chaos is not None and self.chaos.any_faults:
             stall = self.chaos.stall(blk)
@@ -2496,25 +2564,26 @@ class ServeLoop:
             self.counters["chaos_faults"] += int(fault.sum())
         else:
             fault = np.zeros((steps, self.lanes), bool)
-        t_disp = time.monotonic()
-        (self.state, self.tok, active, rem, keys, poison, toks,
-         emitted) = fn(*self._block_args(fault))
-        self._lane_keys = np.asarray(keys).astype(np.uint32)
+        t_disp = time.perf_counter()
+        with span("serve.block.launch"):
+            (self.state, self.tok, active, rem, keys, poison, toks,
+             emitted) = fn(*self._block_args(fault))
+        (keys, host_toks, host_emit, host_poison, active,
+         rem) = self._host("block", lambda *xs: [np.asarray(x) for x in xs],
+                           keys, toks, emitted, poison, active, rem)
+        self._lane_keys = keys.astype(np.uint32)     # [lanes, 2]
         self.counters["decode_blocks"] += 1
         # knob values ride in as [lanes] arrays, so the jit cache holds ONE
         # program per (steps, window) regardless of the knob mix on board
         self.counters["decode_block_programs"] = fn._cache_size()
-        host_toks = np.asarray(toks)                       # [steps, lanes]
-        host_emit = np.asarray(emitted)                    # [steps, lanes]
-        host_poison = np.asarray(poison)                   # [lanes]
         # per-block wall seconds (host-sync included): feeds the
         # backpressure retry_after hint; an EMA so one noisy block
         # doesn't swing the estimate
-        dt = time.monotonic() - t_disp
+        dt = time.perf_counter() - t_disp
         self._block_s_ema = (dt if self._block_s_ema is None
                              else 0.8 * self._block_s_ema + 0.2 * dt)
-        self.active = np.asarray(active).copy()
-        self.remaining = np.asarray(rem).astype(np.int32)
+        self.active = np.array(active)
+        self.remaining = rem.astype(np.int32)
         # per-shard emission accounting (host-side — the ONLY cross-shard
         # traffic the sharded engine has)
         self._shard_tokens += host_emit.sum(axis=0).reshape(
@@ -2528,10 +2597,19 @@ class ServeLoop:
                 if rid is not None:
                     self.stats[rid].t_first = now
             self.outputs[lane].extend(new)
+        # the public per-block record: per lane that was decoding,
+        # (prompt length, tokens it had, tokens this block emitted)
+        rec.attrs.update(
+            window=window or self.model.decode_slots,
+            tokens=int(host_emit.sum()),
+            per_lane=[(self.stats[rid].prompt_len, n0,
+                       int(host_emit[:, i].sum()))
+                      for (i, rid), n0 in zip(lanes, before)])
         # poisoned lanes never take the normal EOS/budget finish path —
         # they are quarantined and their requests retried
-        for lane in np.flatnonzero(was_active & ~self.active
-                                   & ~host_poison):
+        done = np.flatnonzero(was_active & ~self.active & ~host_poison)
+        rec.attrs["finished"] = len(done)
+        for lane in done:
             self._finish_lane(int(lane), now)
         for lane in np.flatnonzero(host_poison & was_active):
             self._quarantine_lane(int(lane), now)
@@ -2596,6 +2674,7 @@ class ServeLoop:
                 if st.t_first < st.t_admit:
                     st.t_first = now
                 st.occupancy = self._lane_occupancy(lane)
+                self._record_request(st)
                 self.completed.append(st)
                 self.done.append(st.tokens)
                 self._finished.add(rid)
@@ -2629,6 +2708,7 @@ class ServeLoop:
         st.outcome = outcome
         st.detail = detail
         st.occupancy = self._lane_occupancy(lane)
+        self._record_request(st)
         self.completed.append(st)
         self.done.append(st.tokens)
         self._finished.add(rid)
@@ -2648,11 +2728,29 @@ class ServeLoop:
             else:
                 self._budget_done += 1
 
+    def _record_request(self, st: RequestStats) -> None:
+        """The resolved request's spans: `serve.request.queue` from its
+        arrival to the start of the admission that took it to its first
+        token, then `serve.request.first_token` from there to `t_first`
+        (the two add up to its ttft); a request never admitted has only
+        the queue span, to its resolution, with its outcome."""
+        if self._t0 is None:                   # refused before any run
+            return
+        arrival = self._t0 + st.t_arrival
+        start = self._admit_t.pop(st.rid, None)
+        if start is None:
+            add_span("serve.request.queue", arrival, self._t0 + st.t_done,
+                     rid=st.rid, outcome=st.outcome)
+            return
+        add_span("serve.request.queue", arrival, start, rid=st.rid)
+        add_span("serve.request.first_token", start, self._t0 + st.t_first,
+                 rid=st.rid, tokens=len(st.tokens))
+
     def _lane_occupancy(self, lane: int) -> float:
         kv = self.state.kv if self.state is not None else None
         if kv is None:
             return 0.0
-        fill = np.asarray(kv.fill)                         # [L, lanes]
+        fill = self._host("fill", np.asarray, kv.fill)     # [L, lanes]
         return float(fill[:, lane].mean() / kv.slots)
 
     # -- driver ---------------------------------------------------------------
@@ -2672,36 +2770,37 @@ class ServeLoop:
         (`_fail_stuck`) instead of spinning forever. A chaos blackout is
         exempted (it expires with the round counter)."""
         if self._t0 is None:
-            self._t0 = time.monotonic()
+            self._t0 = time.perf_counter()
         idle = 0
         while (self._arrived_count or self._arrivals or self._reserved
                or self.active.any() or self._pending is not None):
             self._rounds += 1
-            self._sweep_lanes(self._now())
-            admitted = self.schedule()
-            self._pressure_tick()
-            stepped = self._advance_chunked()
-            if self.active.any():
-                self._step_block()
-            elif stepped or admitted:
-                pass
-            else:
-                # never sleep out the arrival timer of a cancelled
-                # future arrival — resolve it now
-                while self._arrivals and self._arrivals[0].cancelled:
-                    self._resolve_dead(self._arrivals.popleft())
-                if self._arrivals:
-                    wait = self._arrivals[0].arrival - self._now()
-                    if wait > 0:
-                        time.sleep(min(wait, 0.05))
-                elif self._blackout_active():
-                    time.sleep(0.001)   # rounds tick; the blackout expires
-                elif self._arrived_count or self._reserved:
-                    idle += 1
-                    if idle >= 3:
-                        self._fail_stuck()
-                        idle = 0
-                continue
+            with span("serve.round", round=self._rounds):
+                self._sweep_lanes(self._now())
+                admitted = self.schedule()
+                self._pressure_tick()
+                stepped = self._advance_chunked()
+                if self.active.any():
+                    self._step_block()
+                elif stepped or admitted:
+                    pass
+                else:
+                    # never sleep out the arrival timer of a cancelled
+                    # future arrival — resolve it now
+                    while self._arrivals and self._arrivals[0].cancelled:
+                        self._resolve_dead(self._arrivals.popleft())
+                    if self._arrivals:
+                        wait = self._arrivals[0].arrival - self._now()
+                        if wait > 0:
+                            time.sleep(min(wait, 0.05))
+                    elif self._blackout_active():
+                        time.sleep(0.001)  # rounds tick; blackout expires
+                    elif self._arrived_count or self._reserved:
+                        idle += 1
+                        if idle >= 3:
+                            self._fail_stuck()
+                            idle = 0
+                    continue
             idle = 0
         return self.completed
 
@@ -2752,7 +2851,6 @@ class ServeLoop:
                     for k, v in self.counters.items()}
         prefix: Dict[str, float] = {}
         if self.prefix_cache is not None:
-            self._sync_cache_counters()
             counters.update({k: float(v) for k, v in
                              self.prefix_cache.stats().items()})
             lookups = self.counters["prefix_lookups"]
@@ -2916,7 +3014,7 @@ def main(argv=None):
                   f"dedup={agg['prefix_dedup_ratio']:.2f} "
                   f"{int(agg['prefix_cache_bytes'])} bytes, "
                   f"{int(agg['prefix_cache_entries'])} entries, "
-                  f"{loop.counters['prefix_evictions']} evictions")
+                  f"{int(agg['prefix_evictions'])} evictions")
         return
 
     prompts = rng.integers(0, cfg.vocab_size, (args.batch, args.prompt_len))

@@ -242,6 +242,9 @@ def test_serve_prefix_reuse_matches_cold_loop(setup):
     assert agg["prefix_dedup_ratio"] > 0.5                 # 144/256 reused
     assert warm.counters["prefix_copies"] == 3
     assert warm.counters["prefix_tokens_reused"] == 144
+    # the trie's own tallies, read through aggregate(), not mirrored
+    assert agg["prefix_inserts"] == warm.prefix_cache.inserts > 0
+    assert "prefix_inserts" not in warm.counters
     hit_stats = [h.stats for h in hw[1:]]
     assert all(s.prefix_tokens == 48 for s in hit_stats)
     assert all(not s.prefix_exact for s in hit_stats)
