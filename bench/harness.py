@@ -25,7 +25,8 @@ from typing import Any, Dict, List, Optional
 
 import numpy as np
 
-from bench import requests, spec, trace_reduce, traffic, weights
+from bench import (program_spans, requests, spec, trace_reduce, traffic,
+                   weights)
 from bench.reference import Reference
 
 FIRST_TOKENS = 12        # served requests whose first token is compared
@@ -305,6 +306,34 @@ def serve_window(model, params, opts, reqs, seconds: float, lead: float,
             "lead_compiles": win["lead_compiles"]}
 
 
+def timeline(win: Dict[str, Any]) -> str:
+    """Where the window's host time went around its decode blocks: the
+    wait from the window's opening to its first block, the blocks'
+    median and longest durations, the longest gaps between one block's
+    end and the next one's start, and the program's blocking reads by
+    what they wait for. A run that stalls names the block and the read
+    it stalled in."""
+    blocks = win["blocks"]
+    if not blocks:
+        return "no decode blocks in the window"
+    durs = [b["t1"] - b["t0"] for b in blocks]
+    gaps = [b["t0"] - a["t1"] for a, b in zip(blocks, blocks[1:])]
+
+    def top(xs, first=0):
+        return [(i + first, round(x, 4)) for i, x in sorted(
+            enumerate(xs), key=lambda p: -p[1])[:3]]
+
+    w = program_spans.window(win)
+    reads = {} if w is None else {
+        k: (round(t, 4), round(m, 4), n)
+        for k, (t, m, n) in program_spans.waits(
+            w[2], win["t_open"], w[1]).items()}
+    return (f"first block {blocks[0]['t0'] - win['t_open']:.4f} s after the "
+            f"window opened; block s median {float(np.median(durs)):.4f}, "
+            f"longest (block, s) {top(durs)}; longest gaps before a block "
+            f"{top(gaps, 1)}; blocking reads (s, longest, count) {reads}")
+
+
 def end_to_end(win: Dict[str, Any]) -> Dict[str, float]:
     stats = win["window_stats"]
     return {
@@ -316,7 +345,7 @@ def end_to_end(win: Dict[str, Any]) -> Dict[str, float]:
 
 # -- correctness ----------------------------------------------------------------
 #
-# Three numbers, each against the plain reference (bench/reference.py):
+# Four numbers, each against the plain reference (bench/reference.py):
 #
 #   first_token_gap       how far the first served token's logit lies below
 #                         the reference's best after the prompt, over
@@ -324,8 +353,10 @@ def end_to_end(win: Dict[str, Any]) -> Dict[str, float]:
 #                         end to end through prefill and the LM head;
 #   prefill_lockstep_err  the served prefill program on the longest of those
 #                         prompts, the reference beside it at every layer on
-#                         the same q, k, v: attention rows, column sums, and
-#                         the static eviction into the slots;
+#                         the same q, k, v: the attention rows;
+#   prefill_evict_err     the same prefill, the static eviction: the kept
+#                         slots' column sums and keys, and how far a kept
+#                         position falls short of what the eviction needs;
 #   decode_lockstep_err   one decode step of the served program on its own
 #                         state after the window (every lane, the served
 #                         window), the reference beside it at every layer:
@@ -377,10 +408,13 @@ def _rel(a, b, axes):
 
 DECODE_PARTS = ("rows_acc",)
 PREFILL_PARTS = ("rows_max", "rows_rms", "colsum", "keys", "keep")
-# the worst row is left out of the compared number: over 40 layers x 32
-# heads x ~4k rows it reads the extreme of bf16 rounding, 2.7x under the
-# int8 control; the per-head root mean square reads 3.5x under it (PERF.md)
-PREFILL_COMPARED = ("rows_rms", "colsum", "keys", "keep")
+# Parts compared, by number. The worst row is left out: over 40 layers x
+# 32 heads x ~4k rows it reads the extreme of bf16 rounding, 2.7x under
+# the int8 control; the per-head root mean square reads 2.8-3.1x under
+# it over a dozen seeds, the eviction's parts (f32 column sums) 700x and
+# more (PERF.md)
+PREFILL_COMPARED = {"prefill_lockstep_err": ("rows_rms",),
+                    "prefill_evict_err": ("colsum", "keys", "keep")}
 
 
 class Lockstep:
@@ -574,10 +608,11 @@ class Lockstep:
 
         return both
 
-    def prefill(self, params, prompt) -> Dict[str, float]:
-        """{"program": worst error[, "control": ...]} of the prefill of
-        `prompt` at its bucket, over PREFILL_COMPARED; every part's worst
-        value is kept in `self.parts`."""
+    def prefill(self, params, prompt) -> Dict[str, Dict[str, float]]:
+        """{"program": {number: worst error}[, "control": ...]} of the
+        prefill of `prompt` at its bucket, each number over its parts in
+        PREFILL_COMPARED; every part's worst value is kept in
+        `self.parts`."""
         import jax
         import jax.numpy as jnp
         from repro.launch.serve import pad_to_bucket
@@ -588,7 +623,8 @@ class Lockstep:
             ("prefill", len(tokens)),
             lambda: jax.jit(self.model.prefill_one),
             params, jnp.asarray(tokens), jnp.int32(n))
-        return {who: max(v[k] for k in PREFILL_COMPARED)
+        return {who: {num: max(v[k] for k in parts)
+                      for num, parts in PREFILL_COMPARED.items()}
                 for who, v in self.parts.items()}
 
 
@@ -596,6 +632,7 @@ def limits(wl: Dict[str, Any]) -> Dict[str, float]:
     chk = wl["check"]
     return {"first_token_gap": float(chk["first_token_gap_limit"]),
             "prefill_lockstep_err": float(chk["prefill_lockstep_limit"]),
+            "prefill_evict_err": float(chk["prefill_evict_limit"]),
             "decode_lockstep_err": float(chk["decode_lockstep_limit"])}
 
 
@@ -687,13 +724,13 @@ class Bench:
         t0 = time.perf_counter()
         out = {"program": {"first_token_gap": max(first_gaps(
             ref, w, prompts, chosen, self.vocab), default=math.inf),
-            "prefill_lockstep_err": pre.get("program", math.inf),
+            **pre.get("program", {}),
             "decode_lockstep_err": dec["program"]}}
         if "control" in dec:
             out["control"] = {"first_token_gap": max(first_gaps(
                 ref, w, prompts, chosen, self.vocab, other=self.ctl),
                 default=math.inf),
-                "prefill_lockstep_err": pre.get("control", math.inf),
+                **pre.get("control", {}),
                 "decode_lockstep_err": dec["control"]}
         del w
         log(f"prefill lockstep parts {finite_json(self.lockstep.parts)}")
@@ -747,12 +784,15 @@ def run_cell(cell: Dict[str, Any], seed: int, seconds: float, trace: bool,
         f"{np.mean(waits) if waits else float('nan'):.3f} s over "
         f"{len(waits)} admitted")
     log(f"samples: window requests {len(wstats)}, outcomes {outcome}, "
+        f"never admitted at the close "
+        f"{sum(1 for s in wstats if s.lane < 0 and s.t_admit <= 0)}, "
         f"tokens emitted in the window "
         f"{requests.block_tokens(win['blocks'])}, ttft samples "
         f"{len(wstats)}, tpot samples {len(requests.tpot_ms(wstats))}, "
         f"decode blocks in the window {len(win['blocks'])} (run "
         f"{counters['decode_blocks']}), prefill dispatches "
         f"{counters['prefill_dispatches']}")
+    log(f"host timeline: {timeline(win)}")
     log(f"peak_bytes_in_use {mem_peak}")
     ctx = None
     if trace:

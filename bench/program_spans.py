@@ -39,3 +39,14 @@ def admitted_waits(recs, lo: float, hi: float) -> Dict[int, float]:
 
 def mean(xs: List[float]) -> Optional[float]:
     return sum(xs) / len(xs) if xs else None
+
+
+def waits(recs, lo: float, hi: float) -> Dict[str, Tuple[float, float, int]]:
+    """what -> (seconds, longest, count) of the `serve.wait` spans
+    (blocking device->host reads) that start in [lo, hi]."""
+    out: Dict[str, Tuple[float, float, int]] = {}
+    for r in recs:
+        if r.name == "serve.wait" and lo <= r.t0 <= hi:
+            s, m, n = out.get(r.attrs.get("what", "?"), (0.0, 0.0, 0))
+            out[r.attrs.get("what", "?")] = (s + r.dur, max(m, r.dur), n + 1)
+    return out

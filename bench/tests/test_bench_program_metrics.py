@@ -130,3 +130,26 @@ def test_silent_on_a_program_without_spans(ring, monkeypatch):
     monkeypatch.setitem(sys.modules, "repro.launch.spans", None)
     for name in NAMES:
         assert read(name) is None
+
+
+def test_host_timeline_names_the_stall(ring):
+    """The run's log line that says where a window's host time went: the
+    longest block and gap, and the blocking reads from the window's
+    opening on, by what they wait for."""
+    from bench import harness
+    round_with_block(ring, (10.0, 12.0), [(10.15, 10.2), (10.3, 11.8)])
+    round_with_block(ring, (12.0, 14.0), [(12.5, 13.9)])
+    with ring.span("serve.wait", what="fill") as w:
+        pass
+    timed([w], (9.0, 9.9))                   # before the first block
+    win = {"t_open": 8.5, "blocks": [
+        {"t0": 10.0, "t1": 12.0, "lanes": []},
+        {"t0": 12.0, "t1": 14.0, "lanes": []},
+        {"t0": 15.5, "t1": 16.0, "lanes": []}]}
+    line = harness.timeline(win)
+    assert "first block 1.5000 s after the window opened" in line
+    assert "longest (block, s) [(0, 2.0), (1, 2.0), (2, 0.5)]" in line
+    assert "longest gaps before a block [(2, 1.5), (1, 0.0)]" in line
+    assert "'block': (2.95, 1.5, 3)" in line and "'fill': (0.9, 0.9, 1)" in line
+    assert harness.timeline({"t_open": 0.0, "blocks": []}) == \
+        "no decode blocks in the window"

@@ -42,6 +42,10 @@ TINY_LOCKSTEP = 1e-3
 # the lockstep prefill: bf16 attention probabilities read ~1.7e-3 here
 # (the rows' root mean square), the int8 control ~1.4e-2
 TINY_PREFILL = 5e-3
+# the static eviction at prefill: the composed engine's column sums read
+# 3e-4 to 7.4e-4 here, the int8 control 1.8e-3 to 5.7e-3; the lightest
+# tokens kept read far above both
+TINY_EVICT = 2e-3
 GRANITE = {"embedding_multiplier": 12.0, "attention_multiplier": 0.125,
            "residual_multiplier": 0.22, "logits_scaling": 8.0}
 
@@ -58,6 +62,7 @@ def tiny_cell(model=MODEL, limit=TINY_LIMIT):
           "lead_s": 1.0,
           "check": {"first_token_gap_limit": limit,
                     "prefill_lockstep_limit": TINY_PREFILL,
+                    "prefill_evict_limit": TINY_EVICT,
                     "decode_lockstep_limit": TINY_LOCKSTEP}}
     bj = spec.benchmark(ROOT)
     return {"entry": {"name": "tiny", "chips": 1}, "workload": wl,
@@ -77,10 +82,26 @@ def test_tiny_run_is_correct():
     assert r["correct"] and r["failed"] == 0 and r["attempted"] == 4
     assert r["compared"]["first_token_gap"]["value"] <= TINY_LIMIT
     assert r["compared"]["prefill_lockstep_err"]["value"] <= TINY_PREFILL
+    assert r["compared"]["prefill_evict_err"]["value"] <= TINY_EVICT
     assert r["compared"]["decode_lockstep_err"]["value"] <= TINY_LOCKSTEP
     assert list(r)[-1] == "compared"
     assert "sched.lane_occupancy" in r["metrics"]
     assert r["device"]["window_s"] > 0
+
+
+def test_tiny_backlog_run_is_correct():
+    """A backlog cell (granite-gen's arrivals and metrics, lead 0): the
+    window opens at the run's start, every queued request counts, and
+    the cell reports tokens/s and no time to first token."""
+    cell = tiny_cell()
+    cell["workload"].update(arrivals={"kind": "backlog", "per_lane": 16},
+                            lead_s=0)
+    gen = spec.cell("granite-gen", ROOT)
+    cell.update(end_to_end=gen["end_to_end"], per_layer=gen["per_layer"])
+    r = run(cell)
+    assert r["correct"] and r["failed"] == 0 and r["attempted"] == 32
+    assert set(r["metrics"]) == {"output_tok_s", "tpot_p90_ms", "setup_s"}
+    assert r["metrics"]["output_tok_s"]["value"] > 0
 
 
 def test_altered_token_is_caught(monkeypatch):
@@ -122,7 +143,7 @@ def test_fault_is_caught_in_lockstep(monkeypatch, fault):
         def lightest(c, k, v, acc, prune, length=None):
             return fill(c, k, v, -acc, prune, length=length)
         monkeypatch.setattr(pruning, "prefill_fill", lightest)
-        number = "prefill_lockstep_err"
+        number = "prefill_evict_err"
     elif fault == "select_k-1":
         made = harness.program_model
 
@@ -188,7 +209,8 @@ def test_reference_matches_program_in_f32():
             pre = lock.prefill(params, prompt)
         want = ref.first_logits(w, prompt)
         assert np.abs(first - want).max() < 1e-4 * np.abs(want).max()
-        assert dec["program"] < 1e-5 and pre["program"] < 1e-5, (dec, pre)
+        assert dec["program"] < 1e-5, dec
+        assert max(pre["program"].values()) < 1e-5, pre
 
 
 def test_no_tpu_exits_nonzero_without_a_result():

@@ -4,6 +4,7 @@ import sys
 from pathlib import Path
 
 import numpy as np
+import pytest
 
 ROOT = Path(__file__).resolve().parents[2]
 sys.path.insert(0, str(ROOT))
@@ -22,6 +23,10 @@ def test_every_entry_has_its_file():
         assert cell["config"]["name"] == w["config"]
         assert any(m["name"] == "setup_s" for m in cell["end_to_end"])
         assert cell["per_layer"]
+        # a per-layer metric is read only in cells that report what it moves
+        reported = {m["name"] for m in cell["end_to_end"]}
+        for m in cell["per_layer"]:
+            assert m["moves"] in reported, (w["name"], m["name"])
     for m in bj["per_layer"]:
         assert callable(spec.metric_reader(m["name"]))
 
@@ -106,3 +111,44 @@ def test_backlog_onoff_and_shared_prefix():
                           "off_s": 3.0}, "classes": wl["classes"]}
     t = np.array([r["arrival"] for r in traffic.generate(onoff, 3, 20.0, 100, 8)])
     assert len(t) == 20 and ((t % 4.0) < 1.0).all() and (t < 20.0).all()
+
+
+@pytest.mark.parametrize("name,reports,omits", [
+    ("granite-longdoc",
+     ["ttft_p90_s", "tpot_p90_ms", "setup_s", "decode_step.mfu",
+      "ragged_decode_roofline"],
+     ["output_tok_s", "sched.lane_occupancy"]),
+    ("granite-gen",
+     ["output_tok_s", "tpot_p90_ms", "setup_s", "decode_step.mfu",
+      "ragged_decode_roofline", "sched.lane_occupancy", "decode_step.ms",
+      "device.idle_share", "host.round_self_ms"],
+     ["ttft_p90_s", "prefill.ms_per_ktok", "sched.queue_wait_s",
+      "admit.first_token_s"]),
+])
+def test_cell_reports_its_metrics(name, reports, omits):
+    """Tokens per second is judged only above the knee, where the engine
+    sets it; below the knee it is the offered load."""
+    cell = spec.cell(name, ROOT)
+    names = {m["name"] for m in cell["end_to_end"] + cell["per_layer"]}
+    assert set(reports) <= names and not set(omits) & names, names
+
+
+def test_backlog_cell_offers_fixed_work():
+    """granite-gen: 16 requests a lane, all queued at t=0, lengths inside
+    their ranges and the context, the same lengths for every seed."""
+    wl = spec.workload("granite-gen")
+    ctx = spec.config("granite-3-2b")["model"]["max_position_embeddings"]
+    a = traffic.generate(wl, 3, 51.0, 49155, 16)
+    b = traffic.generate(wl, 2**31 + 77, 51.0, 49155, 16)
+    assert len(a) == len(b) == 256
+    assert all(r["arrival"] == 0.0 for r in a + b)
+    c = wl["classes"][0]
+    for r in a:
+        assert c["prompt"]["min"] <= len(r["prompt"]) <= c["prompt"]["max"]
+        assert c["output"]["min"] <= r["max_new"] <= c["output"]["max"]
+        assert len(r["prompt"]) + r["max_new"] <= ctx
+    assert [(len(r["prompt"]), r["max_new"]) for r in a] == \
+        [(len(r["prompt"]), r["max_new"]) for r in b]
+    assert not all((x["prompt"] == y["prompt"]).all() for x, y in zip(a, b))
+    # more output queued than 16 lanes emit in a window at ~1,900 tok/s
+    assert sum(r["max_new"] for r in a) > 1900 * 51
