@@ -93,9 +93,11 @@ def cam_scores(qq, qs, mir, meta):
 
 
 def race(ssel, n_pick: int):
-    """CAM race: mask of the `n_pick` largest entries of ssel [1, n], ties
-    to the lower slot (lax.top_k's order). Each round takes the row max,
-    then the first lane holding it — two lane reductions, no scalar."""
+    """CAM race, row-wise: mask of the `n_pick` largest entries of each
+    row of ssel [R, n], ties to the lower slot (lax.top_k's order). Each
+    round takes every row's max, then the first lane holding it — two
+    lane reductions, no scalar — so R ≤ 8 rows race in the rounds one row
+    takes (a vreg's sublanes)."""
     n = ssel.shape[-1]
     iota = jax.lax.broadcasted_iota(jnp.int32, ssel.shape, 1)
 

@@ -6,7 +6,7 @@ the allocated slot count: its grid walks all S/bs mirror blocks per
 kernel is the fill-aware variant — the software analogue of the paper's
 O(1) per-array CAM race for a *mixed* batch:
 
-  * the per-row live-block count ``ceil(fill / bs)`` is SCALAR-PREFETCHED,
+  * the per-group live-block count (below) is SCALAR-PREFETCHED,
     so it is available to the index maps before the kernel body runs;
   * dead blocks (block index >= live count) remap their DMAs to a block
     the pipeline already holds — Pallas elides the copy when consecutive
@@ -16,17 +16,28 @@ O(1) per-array CAM race for a *mixed* batch:
     its own O(fill), instead of everyone paying O(max over lanes).
 
 Selection is GLOBAL top-k (the ``num_blocks == 1`` semantics of the fused
-kernel / ``ref.fused_decode_ref``), so each row walks its blocks twice —
-grid (BH, 2·nb):
+kernel / ``ref.fused_decode_ref``). A grid step takes R rows, R the
+largest divisor of BH that is <= 8 (`group_rows`): at granite-3-2b's
+BH = lanes x 8 kv heads, a group is one lane's kv heads. Each group walks
+its blocks twice — grid (BH/R, 2·nb):
 
-  steps [0, nb)    CAM pass: score the live mirror blocks into a VMEM
-                   buffer initialised to NEG_INF (dead regions therefore
-                   rank exactly like invalid slots); the last step runs
-                   the race over the whole row and keeps the winner mask;
+  steps [0, nb)    CAM pass: score the live mirror blocks of the group's R
+                   rows into a VMEM buffer initialised to NEG_INF (dead
+                   regions therefore rank exactly like invalid slots);
+                   the last step runs ONE race over the [R, s_pad]
+                   selection buffer — row-wise, so R rows fill the vreg's
+                   sublanes in the rounds one row would take — and keeps
+                   the winner mask;
   steps [nb, 2nb)  current pass: stream the live K/V blocks and attend
-                   over their winners (online softmax); the last step
-                   emits the output and the per-slot charge-domain
+                   each row over its winners (online softmax); the last
+                   step emits the output and the per-slot charge-domain
                    probabilities.
+
+A group's live-block count is the max over its rows' ``ceil(fill / bs)``.
+That is exact for any fills: slots at or past a row's fill are invalid,
+so a block scored past it gives NEG_INF and races like a dead region.
+Where a group's rows share one fill (one lane's kv heads) nothing extra
+is scored.
 
   fills  [BH]        int32           live slot count per row (lane fill)
   q      [BH, G, d]  storage dtype   exact queries
@@ -59,6 +70,7 @@ the decode block.
 from __future__ import annotations
 
 import functools
+import logging
 
 import jax
 import jax.numpy as jnp
@@ -70,6 +82,8 @@ from repro.kernels.fused_decode import (META_ROWS, NEG_INF, PROT, PROT_WIN,
                                         charge_probs, init_softmax,
                                         lane_offset, pack_meta, race)
 
+log = logging.getLogger("repro.kernels")
+
 
 def _ragged_decode_kernel(nblk_ref, q_ref, qq_ref, qs_ref, mir_ref,
                           meta_ref, k_ref, v_ref, out_ref, probs_ref,
@@ -78,7 +92,8 @@ def _ragged_decode_kernel(nblk_ref, q_ref, qq_ref, qs_ref, mir_ref,
     i = pl.program_id(0)
     j = pl.program_id(1)
     live_blocks = nblk_ref[i]
-    g = score_buf.shape[0]
+    rows = sel_buf.shape[0]
+    g = score_buf.shape[0] // rows
 
     @pl.when(j == 0)
     def _init():
@@ -87,18 +102,19 @@ def _ragged_decode_kernel(nblk_ref, q_ref, qq_ref, qs_ref, mir_ref,
         sel_buf[...] = jnp.full_like(sel_buf, g * NEG_INF)
         init_softmax(m_sc, l_sc, o_sc)
 
-    # -- CAM pass: score this block iff it holds any live slot --
+    # -- CAM pass: score this block iff it holds any row's live slot --
     @pl.when(j < live_blocks)
     def _score():
-        meta = meta_ref[0]                                 # [8, bs]
-        raw = cam_scores(qq_ref[0], qs_ref[0], mir_ref[0], meta)
         off = lane_offset(j, bs)
-        score_buf[:, pl.ds(off, bs)] = raw
-        ssel = jnp.sum(raw, axis=0, keepdims=True)         # [1, bs]
-        sel_buf[:, pl.ds(off, bs)] = jnp.where(
-            meta[PROT:PROT + 1] != 0, PROT_WIN, ssel)
+        for r in range(rows):
+            meta = meta_ref[r]                             # [8, bs]
+            raw = cam_scores(qq_ref[r], qs_ref[r], mir_ref[r], meta)
+            score_buf[r * g:(r + 1) * g, pl.ds(off, bs)] = raw
+            ssel = jnp.sum(raw, axis=0, keepdims=True)     # [1, bs]
+            sel_buf[r:r + 1, pl.ds(off, bs)] = jnp.where(
+                meta[PROT:PROT + 1] != 0, PROT_WIN, ssel)
 
-    # -- global CAM race over the whole row; keep the winner mask --
+    # -- one global CAM race over the group's rows; keep the winner mask --
     @pl.when(j == nb - 1)
     def _race():
         sel_buf[...] = jnp.where(race(sel_buf[...], k_sel_n), 1.0, 0.0)
@@ -108,16 +124,27 @@ def _ragged_decode_kernel(nblk_ref, q_ref, qq_ref, qs_ref, mir_ref,
 
     @pl.when((jj >= 0) & (jj < live_blocks))
     def _attend():
-        sel = sel_buf[:, pl.ds(lane_offset(jj, bs), bs)] > 0.5
-        attend_block(q_ref[0], k_ref[0], v_ref[0], meta_ref[0], sel, scale,
-                     m_sc, l_sc, o_sc,
-                     n_valid=None if s % bs == 0 else s - jj * bs)
+        off = lane_offset(jj, bs)
+        for r in range(rows):
+            sel = sel_buf[r:r + 1, pl.ds(off, bs)] > 0.5
+            attend_block(q_ref[r], k_ref[r], v_ref[r], meta_ref[r], sel,
+                         scale, m_sc.at[r], l_sc.at[r], o_sc.at[r],
+                         n_valid=None if s % bs == 0 else s - jj * bs)
 
     @pl.when(j == 2 * nb - 1)
     def _flush():
-        out_ref[0] = o_sc[...] / jnp.maximum(l_sc[...], 1e-30)
+        out_ref[...] = o_sc[...] / jnp.maximum(l_sc[...], 1e-30)
         # -- charge-domain mode: per-slot approximate probabilities --
-        probs_ref[0] = charge_probs(score_buf[...], scale)
+        for r in range(rows):
+            probs_ref[0, r:r + 1, :] = charge_probs(
+                score_buf[r * g:(r + 1) * g], scale)
+
+
+def group_rows(bh: int) -> int:
+    """Rows a grid step takes: the largest divisor of BH that is <= 8, so
+    the race fills the vreg's 8 sublanes (a lane's 8 kv heads for
+    granite-3-2b)."""
+    return max(r for r in range(1, min(bh, 8) + 1) if bh % r == 0)
 
 
 @functools.partial(jax.jit,
@@ -144,9 +171,15 @@ def ragged_decode(fills: jax.Array, q: jax.Array, qq: jax.Array,
     bs = -(-min(block_s, s) // align) * align
     s_pad = -(-s // bs) * bs
     nb = s_pad // bs
+    rows = group_rows(bh)
+    log.info("ragged_decode: %d rows a grid step, grid (%d, %d) for BH %d, "
+             "G %d, d %d, S %d", rows, bh // rows, 2 * nb, bh, g, d, s)
     meta = pack_meta(mscale, kscale, vscale, valid, prot, s_pad)
     nblk = jnp.clip(-(-jnp.minimum(fills.astype(jnp.int32), s) // bs),
                     0, nb)
+    # a group walks the blocks of its longest row: slots past a row's fill
+    # are invalid, so its extra blocks score NEG_INF and race as dead
+    nblk = jnp.max(nblk.reshape(bh // rows, rows), axis=1)
 
     def blk(j, cnt):
         # dead blocks re-fetch the last live block: the pipeline sees an
@@ -166,30 +199,30 @@ def ragged_decode(fills: jax.Array, q: jax.Array, qq: jax.Array,
                                k_sel_n=select_k, scale=1.0 / (d ** 0.5))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
-        grid=(bh, 2 * nb),
+        grid=(bh // rows, 2 * nb),
         in_specs=[
-            pl.BlockSpec((1, g, d), lambda i, j, c: (i, 0, 0)),   # q
-            pl.BlockSpec((1, g, d), lambda i, j, c: (i, 0, 0)),   # qq
-            pl.BlockSpec((1, g, 1), lambda i, j, c: (i, 0, 0)),   # qscale
-            pl.BlockSpec((1, bs, d),
+            pl.BlockSpec((rows, g, d), lambda i, j, c: (i, 0, 0)),  # q
+            pl.BlockSpec((rows, g, d), lambda i, j, c: (i, 0, 0)),  # qq
+            pl.BlockSpec((rows, g, 1), lambda i, j, c: (i, 0, 0)),  # qscale
+            pl.BlockSpec((rows, bs, d),
                          lambda i, j, c: (i, score_blk(i, j, c), 0)),
-            pl.BlockSpec((1, META_ROWS, bs),
+            pl.BlockSpec((rows, META_ROWS, bs),
                          lambda i, j, c: (i, 0, meta_blk(i, j, c))),
-            pl.BlockSpec((1, bs, d),
+            pl.BlockSpec((rows, bs, d),
                          lambda i, j, c: (i, attend_blk(i, j, c), 0)),
-            pl.BlockSpec((1, bs, dv),
+            pl.BlockSpec((rows, bs, dv),
                          lambda i, j, c: (i, attend_blk(i, j, c), 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, g, dv), lambda i, j, c: (i, 0, 0)),
-            pl.BlockSpec((1, 1, s_pad), lambda i, j, c: (i, 0, 0)),
+            pl.BlockSpec((rows, g, dv), lambda i, j, c: (i, 0, 0)),
+            pl.BlockSpec((1, rows, s_pad), lambda i, j, c: (i, 0, 0)),
         ],
         scratch_shapes=[
-            pltpu.VMEM((g, s_pad), jnp.float32),     # score buffer
-            pltpu.VMEM((1, s_pad), jnp.float32),     # race input → winners
-            pltpu.VMEM((g, 1), jnp.float32),         # running max
-            pltpu.VMEM((g, 1), jnp.float32),         # running denom
-            pltpu.VMEM((g, dv), jnp.float32),        # running output
+            pltpu.VMEM((rows * g, s_pad), jnp.float32),  # score buffer
+            pltpu.VMEM((rows, s_pad), jnp.float32),     # race input → winners
+            pltpu.VMEM((rows, g, 1), jnp.float32),      # running max
+            pltpu.VMEM((rows, g, 1), jnp.float32),      # running denom
+            pltpu.VMEM((rows, g, dv), jnp.float32),     # running output
         ],
     )
     out, probs = pl.pallas_call(
@@ -197,11 +230,11 @@ def ragged_decode(fills: jax.Array, q: jax.Array, qq: jax.Array,
         grid_spec=grid_spec,
         out_shape=[
             jax.ShapeDtypeStruct((bh, g, dv), jnp.float32),
-            jax.ShapeDtypeStruct((bh, 1, s_pad), jnp.float32),
+            jax.ShapeDtypeStruct((bh // rows, rows, s_pad), jnp.float32),
         ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(nblk, q, qq, qscale.astype(jnp.float32)[..., None], mirror, meta,
       k, v)
-    return out, probs[:, 0, :s]
+    return out, probs.reshape(bh, s_pad)[:, :s]

@@ -17,13 +17,16 @@ import jax.numpy as jnp
 import pytest
 
 from repro.kernels.fused_decode import fused_decode
-from repro.kernels.ragged_decode import ragged_decode
+from repro.kernels.ragged_decode import group_rows, ragged_decode
 
 LANES = 8
 SLOTS = 1088           # heavy 1024 + reserve 64, as chip_smoke.py serves
 SELECT_K = 128
-# (kv heads, query group, head dim): granite-3-2b GQA, longchat-7b MHA
-WIDTHS = {"granite-3-2b": (8, 4, 64), "longchat-7b": (32, 1, 128)}
+# (kv heads, query group, head dim): granite-3-2b GQA, longchat-7b MHA,
+# phi3-medium-14b GQA with 10 kv heads (ragged_decode's 8-row groups
+# straddle lanes)
+WIDTHS = {"granite-3-2b": (8, 4, 64), "longchat-7b": (32, 1, 128),
+          "phi3-medium-14b": (10, 4, 128)}
 
 
 @pytest.fixture(scope="module")
@@ -52,9 +55,9 @@ def no_persistent_cache():
     compilation_cache.reset_cache()
 
 
-def _operands(sharding, model, kv_dtype, q_dtype):
+def _operands(sharding, model, kv_dtype, q_dtype, lanes=LANES):
     hk, g, d = WIDTHS[model]
-    bh = LANES * hk
+    bh = lanes * hk
 
     def sds(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
@@ -103,3 +106,33 @@ def test_decode_kernel_compiles_for_v5e(one_chip, no_persistent_cache,
             lowered = fn.lower(*args)
         hlo = lowered.compile().as_text()
     assert "tpu_custom_call" in hlo, f"{kernel} did not lower to Mosaic"
+
+
+def _pallas_grid(jaxpr):
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            return eqn.params["grid_mapping"].grid
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            grid = _pallas_grid(sub)
+            if grid is not None:
+                return grid
+    return None
+
+
+def test_ragged_decode_groups_a_lanes_kv_heads(one_chip, caplog):
+    """granite-3-2b at 16 lanes: BH = 128 rows, 8 a grid step (one lane's
+    kv heads), so the grid is (16, 2·nb) and the CAM race runs once per
+    lane per layer, not once per kv head."""
+    hk, g, d = WIDTHS["granite-3-2b"]
+    bh = 2 * LANES * hk
+    nb = -(-SLOTS // 512)                       # block_s 512, 128-aligned
+    fills, args = _operands(one_chip, "granite-3-2b", "bf16",
+                            jnp.bfloat16, lanes=2 * LANES)
+    with caplog.at_level("INFO", logger="repro.kernels"):
+        jaxpr = jax.make_jaxpr(lambda f, *a: ragged_decode(
+            f, *a, select_k=SELECT_K))(fills, *args)
+    assert group_rows(bh) == 8
+    assert _pallas_grid(jaxpr.jaxpr) == (bh // 8, 2 * nb) == (16, 6)
+    assert f"8 rows a grid step, grid (16, {2 * nb})" in caplog.text
+    assert [group_rows(n) for n in (80, 160, 12, 7, 13, 1)] == \
+        [8, 8, 6, 7, 1, 1]

@@ -233,13 +233,19 @@ def test_serve_windowed_parity_and_retrace_bound():
 
 
 def _ragged_args(bh, g, d, dv, s, fills, key=0, quantized=False,
-                 prot_frac=0.1):
+                 prot_frac=0.1, ties=False):
+    """Random operands on per-row fill prefixes. `ties` draws the mirror
+    from {-1, 0, 1} with unit scales, so many slots score exactly alike
+    and the race's order among them decides the winners."""
     ks = jax.random.split(jax.random.PRNGKey(key), 10)
     q = jax.random.normal(ks[0], (bh, g, d))
     qq = jax.random.randint(ks[1], (bh, g, d), -7, 8, jnp.int8)
     qs = jax.random.uniform(ks[2], (bh, g)) + 0.05
-    mirror = jax.random.randint(ks[3], (bh, s, d), -7, 8, jnp.int8)
+    lim = 2 if ties else 8
+    mirror = jax.random.randint(ks[3], (bh, s, d), 1 - lim, lim, jnp.int8)
     ms = jax.random.uniform(ks[4], (bh, s)) + 0.05
+    if ties:
+        qs, ms = jnp.ones_like(qs), jnp.ones_like(ms)
     if quantized:
         k = jax.random.randint(ks[5], (bh, s, d), -127, 128, jnp.int8)
         v = jax.random.randint(ks[6], (bh, s, dv), -127, 128, jnp.int8)
@@ -257,17 +263,29 @@ def _ragged_args(bh, g, d, dv, s, fills, key=0, quantized=False,
     return fills, (q, qq, qs, mirror, ms, kscale, vscale, valid, prot, k, v)
 
 
-@pytest.mark.parametrize("bh,g,d,dv,s,sk,fills,quantized", [
-    (4, 2, 32, 32, 64, 16, [5, 30, 64, 0], False),   # mixed + empty + full
-    (3, 4, 16, 24, 100, 8, [100, 17, 42], True),     # int8, ragged S
-    (2, 1, 16, 16, 48, 48, [10, 48], False),         # select_k == S
+@pytest.mark.parametrize("bh,g,d,dv,s,sk,fills,quantized,block_s,ties", [
+    (4, 2, 32, 32, 64, 16, [5, 30, 64, 0], False, 16, False),
+    (3, 4, 16, 24, 100, 8, [100, 17, 42], True, 16, False),  # int8, ragged S
+    (2, 1, 16, 16, 48, 48, [10, 48], False, 16, False),      # select_k == S
+    # granite-3-2b's shape: two lanes x 8 kv heads, one lane's heads a
+    # grid step, S = 1088 slots in blocks of 512 (the last one partial)
+    (16, 4, 64, 64, 1088, 128, [0] * 8 + [700] * 8, False, 512, False),
+    # tie-heavy scores: the race keeps the lower slot in every row
+    (8, 4, 16, 16, 96, 24, [96, 40, 0, 77] * 2, False, 32, True),
+    (16, 1, 16, 16, 64, 20, [64] * 16, True, 16, True),
+    # 6 rows a step over 3 lanes x 4 kv heads: groups mix fills (and a
+    # fill-0 row), each walks the blocks of its longest row
+    (12, 2, 16, 16, 80, 12, [0] * 4 + [61] * 4 + [33] * 4, False, 16,
+     False),
 ])
-def test_ragged_kernel_matches_ref(bh, g, d, dv, s, sk, fills, quantized):
-    """Dead-block early exit must not change a bit of the math: parity
-    with the global-selection oracle on per-lane fill prefixes."""
+def test_ragged_kernel_matches_ref(bh, g, d, dv, s, sk, fills, quantized,
+                                   block_s, ties):
+    """Dead-block early exit and row grouping must not change a bit of
+    the math: parity with the global-selection oracle on per-lane fill
+    prefixes."""
     fl, args = _ragged_args(bh, g, d, dv, s, fills, key=s + sk,
-                            quantized=quantized)
-    out_k, probs_k = ragged_decode(fl, *args, select_k=sk, block_s=16,
+                            quantized=quantized, ties=ties)
+    out_k, probs_k = ragged_decode(fl, *args, select_k=sk, block_s=block_s,
                                    interpret=True)
     out_r, probs_r = ref.fused_decode_ref(*args, select_k=sk, num_blocks=1)
     np.testing.assert_allclose(np.asarray(out_k), np.asarray(out_r),
