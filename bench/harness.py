@@ -12,6 +12,7 @@ token and decoding lanes stop at the next block.
 """
 from __future__ import annotations
 
+import dataclasses
 import gc
 import json
 import math
@@ -30,7 +31,10 @@ from bench import (program_spans, requests, spec, trace_reduce, traffic,
 from bench.reference import Reference
 
 FIRST_TOKENS = 12        # served requests whose first token is compared
-PROGRAM_EPS = 1e-6       # the dense Model's norm epsilon
+# What the dense Model computes where its ModelConfig has no field of the
+# name: no o-projection bias, no MLP biases, norm epsilon 1e-6, no window
+PROGRAM_DEFAULTS = {"out_bias": False, "mlp_bias": False, "norm_eps": 1e-6,
+                    "sliding_window": None}
 
 
 def log(msg: str) -> None:
@@ -80,20 +84,45 @@ def peak_table(kind: str) -> Dict[str, float]:
 
 def program_model(cfg_spec: Dict[str, Any]):
     """The program's Model for the configuration file, checked against
-    the repo's registered configuration of the same model when named."""
+    the repo's registered configuration of the same model when named.
+
+    A block the program cannot serve as stated is refused by name: an
+    activation it has no form of (its `gelu` is the tanh form), and an
+    o-projection bias, MLP biases, a norm epsilon other than 1e-6 or a
+    sliding window while `ModelConfig` has no field `out_bias`,
+    `mlp_bias`, `norm_eps` or `sliding_window` (one error lists every
+    field missing). Where it has the field, the file's value is set."""
     from repro.configs.base import ModelConfig, PruneConfig, get_config
     from repro.models.transformer import Model
     m = cfg_spec["model"]
+    act = {"gated": "swiglu", "gelu_tanh": "gelu"}.get(weights.activation(m))
+    if act is None:
+        raise ValueError(f"hidden_act {m['hidden_act']!r}: the program's "
+                         f"MLP has no such form (its gelu is tanh-GELU)")
     fields = dict(
         family="dense", num_layers=m["num_hidden_layers"],
         d_model=m["hidden_size"], n_heads=m["num_attention_heads"],
         n_kv_heads=m["num_key_value_heads"], head_dim=m["head_dim"],
         d_ff=m["intermediate_size"], vocab_size=m["vocab_size"],
-        norm="rms" if m["norm"] == "rmsnorm" else "ln",
-        act="swiglu" if weights.gated(m) else "gelu", pos="rope",
+        norm="ln" if weights.layernorm(m) else "rms",
+        act=act, pos="rope",
         rope_theta=float(m["rope_theta"]),
         tie_embeddings=bool(m["tie_word_embeddings"]),
         qkv_bias=weights.qkv_bias(m))
+    stated = {"out_bias": weights.out_bias(m),
+              "mlp_bias": weights.mlp_bias(m),
+              "norm_eps": weights.norm_eps(m),
+              "sliding_window": weights.sliding_window(m)}
+    have = {f.name for f in dataclasses.fields(ModelConfig)}
+    missing = [k for k, v in stated.items()
+               if k not in have and v != PROGRAM_DEFAULTS[k]]
+    if missing:
+        raise ValueError(
+            "the configuration states " + ", ".join(
+                f"{k}={stated[k]!r}" for k in missing)
+            + "; the program's ModelConfig has no field "
+            + ", ".join(missing))
+    fields.update({k: v for k, v in stated.items() if k in have})
     if cfg_spec.get("repo_config"):
         mc = get_config(cfg_spec["repo_config"])
         differ = {k: (getattr(mc, k), v) for k, v in fields.items()
@@ -103,8 +132,6 @@ def program_model(cfg_spec: Dict[str, Any]):
                              f"{mc.name} differ: {differ}")
     else:
         mc = ModelConfig(name=cfg_spec["name"], **fields)
-    if float(m.get("rms_norm_eps", PROGRAM_EPS)) != PROGRAM_EPS:
-        raise ValueError(f"the program's norms use epsilon {PROGRAM_EPS}")
     if mc.param_dtype != "bfloat16" or mc.compute_dtype != "bfloat16":
         raise ValueError("the configuration states bfloat16 weights and "
                          "activations")
@@ -437,7 +464,7 @@ class Lockstep:
     heads. One compiled program per shape and process."""
 
     def __init__(self, model, m, p, control: bool):
-        self.model, self.p, self.control = model, p, control
+        self.model, self.control = model, control
         self.ref = Reference(m, p, "f32", prompt_pad=1)
         self.ref8 = Reference(m, p, "int8", prompt_pad=1) if control else None
         # the program's query carries W_q's fold (bench/weights.py): the
@@ -543,11 +570,12 @@ class Lockstep:
         """[PREFILL_PARTS, Hk]: per kv head, the worst and the root mean
         square relative error of the attention rows; the kept slots'
         column sums (relative to the largest) and keys against the
-        reference's; how far a kept unprotected position's column sum
+        reference's; how far a kept position's rank (`Reference.rank`)
         falls short of what the reference's static eviction needs
-        (relative to the largest)."""
+        (relative to the largest column sum): a protected one never, one
+        the reference never keeps (past the prompt, or out of a window)
+        by inf."""
         import jax.numpy as jnp
-        p = self.p
         N, hq, _ = o_r.shape
         hk = c_r.shape[0]
         real = (jnp.arange(N) < n)[:, None]
@@ -563,8 +591,8 @@ class Lockstep:
         keys = jnp.where(valid[..., None], jnp.abs(kk - k_at), 0.0).max(
             (1, 2)) / jnp.maximum(jnp.abs(kt).max((1, 2)), 1e-30)
         _, need = self.ref.keep(c_r, n)
-        prot = (pos < p["sink_tokens"]) | (pos >= n - p["recent_window"])
-        keep = jnp.where(valid & ~prot, jnp.maximum(need[:, None] - c_at, 0),
+        r_at = jnp.take_along_axis(self.ref.rank(c_r, n), sp, 1)
+        keep = jnp.where(valid, jnp.maximum(need[:, None] - r_at, 0),
                          0.0).max(-1) / cmax
         return jnp.stack([rows_max, rows_rms, colsum, keys, keep])
 

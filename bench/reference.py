@@ -6,7 +6,9 @@ precision HIGHEST, with no kernels, no batching across requests and
 nothing imported from the program. It follows the published
 configuration: the embedding, attention, residual and logit multipliers
 where the configuration states them (Granite), else the plain
-transformer's 1, 1/sqrt(head_dim), 1 and 1.
+transformer's 1, 1/sqrt(head_dim), 1 and 1; the biases of each group the
+file switches on, the file's norm epsilon and the MLP's activation by
+its published name (bench/weights.py).
 
   prefill   causal attention over the prompt; each kv head's
             accumulated attention column sums (summed over its query
@@ -26,6 +28,25 @@ transformer's 1, 1/sqrt(head_dim), 1 and 1.
             accumulated scores;
   logits    final norm, then the LM head.
 
+A sliding window W (`sliding_window`) is served so, a query at position
+t seeing the key at s iff t - W < s <= t:
+
+  prefill   the query at t attends to the keys at t - W < s <= t, and
+            the column sums are of that attention;
+  keep      a position that no later query can see (s <= n - W, for a
+            prompt of n) is never kept or protected, sinks included: it
+            ranks below every live position and fills no slot while
+            live positions remain;
+  attend    at query position `step`, a slot holding pos <= step - W is
+            dead: it is never protected, it is evicted before any live
+            slot (the oldest dead slot first), and it is left out of the
+            CAM scores, the winners (which are drawn from live slots
+            only), exact attention and the accumulation (its score stays
+            as it was). It still counts, as every occupied slot does, in
+            the mean that starts the new token's score.
+
+Without a window every path is the windowless one, bit for bit.
+
 `precision="int8"` is the control: every matmul (the weights', the LM
 head's and exact attention's) takes int8 operands, each scaled per row
 along the contracted axis: the step below the served bfloat16 that would
@@ -39,6 +60,8 @@ from typing import Dict, NamedTuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from bench import weights
 
 HIGHEST = jax.lax.Precision.HIGHEST
 NEG = -1e30
@@ -137,7 +160,9 @@ class Reference:
                  prompt_pad: int = 4096, q_block: int = 512):
         self.m, self.p, self.precision = m, p, precision
         self.mult = multipliers(m)
-        self.eps = float(m.get("rms_norm_eps", 1e-6))
+        self.eps = weights.norm_eps(m)
+        self.act = weights.activation(m)
+        self.window = weights.sliding_window(m)
         self.hq, self.hk = m["num_attention_heads"], m["num_key_value_heads"]
         self.dh = m["head_dim"]
         self.g = self.hq // self.hk
@@ -152,18 +177,19 @@ class Reference:
     def _mm(self, x, w):
         return matmul(x, w, self.precision)
 
+    def _linear(self, x, lw, w, b):
+        """x @ lw[w], plus lw[b] where the layer has that bias."""
+        y = self._mm(x, lw[w])
+        return y + lw[b].astype(jnp.float32) if b in lw else y
+
     def _norm(self, x, lw, which):
         return norm(x, lw[which + "_w"], lw.get(which + "_b"), self.m["norm"],
                     self.eps)
 
     def _qkv(self, h, lw, pos):
-        q = self._mm(h, lw["wq"])
-        k = self._mm(h, lw["wk"])
-        v = self._mm(h, lw["wv"])
-        if "bq" in lw:
-            q = q + lw["bq"].astype(jnp.float32)
-            k = k + lw["bk"].astype(jnp.float32)
-            v = v + lw["bv"].astype(jnp.float32)
+        q = self._linear(h, lw, "wq", "bq")
+        k = self._linear(h, lw, "wk", "bk")
+        v = self._linear(h, lw, "wv", "bv")
         lead = h.shape[:-1]
         q = q.reshape(lead + (self.hq, self.dh))
         k = k.reshape(lead + (self.hk, self.dh))
@@ -172,12 +198,12 @@ class Reference:
         return rope(q, pos, theta), rope(k, pos, theta), v
 
     def _mlp(self, h, lw):
-        up = self._mm(h, lw["w_up"])
-        if "w_gate" in lw:
-            a = jax.nn.silu(self._mm(h, lw["w_gate"])) * up
+        up = self._linear(h, lw, "w_up", "b_up")
+        if self.act == "gated":
+            a = jax.nn.silu(self._linear(h, lw, "w_gate", "b_gate")) * up
         else:
-            a = jax.nn.gelu(up, approximate=True)
-        return self._mm(a, lw["w_down"])
+            a = jax.nn.gelu(up, approximate=self.act == "gelu_tanh")
+        return self._linear(a, lw, "w_down", "b_down")
 
     def _logits(self, w, x):
         h = norm(x, w["final_norm_w"], w.get("final_norm_b"), self.m["norm"],
@@ -208,7 +234,10 @@ class Reference:
             rows = bi * qb + jnp.arange(qb)
             s = einsum("tkgd,skd->kgts", qbk, k, self.precision) \
                 * self.mult["attention"]
-            s = jnp.where(rows[:, None] >= pos[None, :], s, NEG)
+            see = rows[:, None] >= pos[None, :]
+            if self.window is not None:
+                see = see & (rows[:, None] - pos[None, :] < self.window)
+            s = jnp.where(see, s, NEG)
             pr = jax.nn.softmax(s, axis=-1)
             o = einsum("kgts,skd->tkgd", pr, v, self.precision)
             live = (rows < n).astype(jnp.float32)
@@ -219,17 +248,24 @@ class Reference:
                               (jnp.arange(P // qb), qg))
         return o.reshape(P, hk * g, dh), acc
 
-    def keep(self, acc, n):
-        """Static eviction at prefill: the positions [Hk, heavy] whose
-        column sums rank highest (the first `sink` and last `recent`
-        always), and the least ranked value a kept unprotected position
-        needs [Hk]."""
+    def rank(self, acc, n):
+        """What static eviction ranks the prompt's positions by [Hk, P]:
+        their column sums; +inf for the protected (the first `sink` and
+        last `recent`); -inf past the prompt and, under a window, where
+        no later query can see (s <= n - W), sinks included."""
         p = self.p
         pos = jnp.arange(acc.shape[-1])
         protect = (pos < p["sink_tokens"]) | (pos >= n - p["recent_window"])
         ranked = jnp.where(protect, jnp.inf, acc)
-        ranked = jnp.where(pos < n, ranked, -jnp.inf)
-        top, idx = jax.lax.top_k(ranked, self.heavy)
+        seen = pos < n
+        if self.window is not None:
+            seen = seen & (pos > n - self.window)
+        return jnp.where(seen, ranked, -jnp.inf)
+
+    def keep(self, acc, n):
+        """Static eviction at prefill: the positions [Hk, heavy] that
+        rank highest, and the least rank a kept position needs [Hk]."""
+        top, idx = jax.lax.top_k(self.rank(acc, n), self.heavy)
         return idx, top[:, -1]
 
     def _prefill(self, w, tokens, n):
@@ -243,7 +279,7 @@ class Reference:
         def layer(x, lw):
             q, k, v = self._qkv(self._norm(x, lw, "ln1"), lw, pos)
             o, _ = self.prefill_attend(q, k, v, n)
-            x = x + res * self._mm(o.reshape(P, -1), lw["wo"])
+            x = x + res * self._linear(o.reshape(P, -1), lw, "wo", "bo")
             x = x + res * self._mlp(self._norm(x, lw, "ln2"), lw)
             return x, None
 
@@ -252,48 +288,71 @@ class Reference:
 
     # -- one decode step of one layer -------------------------------------------
 
+    def write(self, c: Layer, k_new, v_new) -> Layer:
+        """The new token's write, the first part of `attend`: into the
+        next free slot, or once the slots are full over the slot evicted
+        (a dead one, the oldest first; else the evictable one with the
+        least accumulated score), its score starting at the mean of the
+        occupied slots' (as they stand before the write, dead ones
+        included). Returns the cache after it, `step` one on."""
+        p = self.p
+        S = c.acc.shape[-1]
+        acc, valid, posn, step = c.acc, c.valid, c.pos, c.step
+        prot = valid & (((posn >= 0) & (posn < p["sink_tokens"]))
+                        | (posn >= step - p["recent_window"]))
+        evict = jnp.argmin(jnp.where(valid & ~prot, acc, jnp.inf), -1)
+        if self.window is not None:
+            dead = valid & (posn <= step - self.window)
+            oldest = jnp.argmin(jnp.where(dead, posn, step), -1)
+            evict = jnp.where(dead.any(-1), oldest, evict)
+        slot = jnp.where(c.fill >= S, evict, c.fill)      # [Hk]
+        occupied = jnp.maximum(jnp.sum(valid, -1), 1)
+        init = jnp.sum(jnp.where(valid, acc, 0.0), -1) / occupied
+        kqn, ksn = quantize(k_new, p["score_bits"])
+        at = jnp.arange(S)[None, :] == slot[:, None]      # [Hk, S]
+
+        def put(new, old):
+            return jnp.where(at[..., None], new[:, None],
+                             old.astype(jnp.float32))
+
+        return Layer(
+            k=put(k_new, c.k), v=put(v_new, c.v), kq=put(kqn, c.kq),
+            ks=jnp.where(at, ksn[:, None], c.ks),
+            acc=jnp.where(at, init[:, None], acc),
+            valid=valid | at, pos=jnp.where(at, step, posn),
+            fill=jnp.minimum(c.fill + 1, S), step=step + 1)
+
+    def select(self, c: Layer, q):
+        """The CAM pass of `attend` on the cache after the write:
+        (approximate scores [Hk, g, S], the live slots [Hk, S], the
+        `select_k` winners [Hk, k])."""
+        p, hk, g, dh = self.p, self.hk, self.g, self.dh
+        valid, posn = c.valid, c.pos
+        qq, qs = quantize(q, p["query_bits"])
+        raw = jnp.einsum("kgd,ksd->kgs", qq.reshape(hk, g, dh), c.kq,
+                         precision=HIGHEST)
+        prot = valid & (((posn >= 0) & (posn < p["sink_tokens"]))
+                        | (posn >= c.step - p["recent_window"]))
+        if self.window is not None:         # the query is at c.step - 1
+            valid = valid & (posn >= c.step - self.window)
+        approx = raw * qs.reshape(hk, g)[..., None] * c.ks[:, None, :]
+        approx = jnp.where(valid[:, None, :], approx, NEG)
+        sel = jnp.where(prot, 1e30, jnp.sum(approx, 1))
+        sel = jnp.where(valid, sel, NEG)
+        _, idx = jax.lax.top_k(sel, p["select_k"])       # [Hk, k]
+        return approx, valid, idx
+
     def attend(self, c: Layer, q, k_new, v_new):
         """One lane's decode step at one layer, on the cache `c` as it
         stands before the step: q [Hq, dh], k_new / v_new [Hk, dh].
         Returns (attention output [Hq, dh], accumulated scores after the
         step [Hk, S])."""
-        p, hk, g, dh = self.p, self.hk, self.g, self.dh
-        S = c.acc.shape[-1]
+        hk, g, dh = self.hk, self.g, self.dh
         scale = self.mult["attention"]
-        k_s, v_s = c.k.astype(jnp.float32), c.v.astype(jnp.float32)
-        kq, ks, acc, valid, posn = c.kq.astype(jnp.float32), c.ks, c.acc, \
-            c.valid, c.pos
-        step = c.step
-        # write: next free slot, else evict the least-scored evictable
-        prot_old = valid & (((posn >= 0) & (posn < p["sink_tokens"]))
-                            | (posn >= step - p["recent_window"]))
-        evict = jnp.argmin(jnp.where(valid & ~prot_old, acc, jnp.inf), -1)
-        slot = jnp.where(c.fill >= S, evict, c.fill)      # [Hk]
-        live = jnp.sum(valid, -1)
-        init = jnp.sum(jnp.where(valid, acc, 0.0), -1) / jnp.maximum(live, 1)
-        kqn, ksn = quantize(k_new, p["score_bits"])
-        at = jnp.arange(S)[None, :] == slot[:, None]      # [Hk, S]
-        k_s = jnp.where(at[..., None], k_new[:, None], k_s)
-        v_s = jnp.where(at[..., None], v_new[:, None], v_s)
-        kq = jnp.where(at[..., None], kqn[:, None], kq)
-        ks = jnp.where(at, ksn[:, None], ks)
-        acc = jnp.where(at, init[:, None], acc)
-        valid = valid | at
-        posn = jnp.where(at, step, posn)
-        step1 = step + 1
-        # CAM pass over the mirror
-        qq, qs = quantize(q, p["query_bits"])
-        raw = jnp.einsum("kgd,ksd->kgs", qq.reshape(hk, g, dh), kq,
-                         precision=HIGHEST)
-        approx = raw * qs.reshape(hk, g)[..., None] * ks[:, None, :]
-        approx = jnp.where(valid[:, None, :], approx, NEG)
-        prot = valid & (((posn >= 0) & (posn < p["sink_tokens"]))
-                        | (posn >= step1 - p["recent_window"]))
-        sel = jnp.where(prot, 1e30, jnp.sum(approx, 1))
-        sel = jnp.where(valid, sel, NEG)
-        _, idx = jax.lax.top_k(sel, p["select_k"])       # [Hk, k]
-        kw = jnp.take_along_axis(k_s, idx[..., None], 1)
-        vw = jnp.take_along_axis(v_s, idx[..., None], 1)
+        c = self.write(c, k_new, v_new)
+        approx, valid, idx = self.select(c, q)
+        kw = jnp.take_along_axis(c.k, idx[..., None], 1)
+        vw = jnp.take_along_axis(c.v, idx[..., None], 1)
         vok = jnp.take_along_axis(valid, idx, 1)
         lg = einsum("kgd,kjd->kgj", q.reshape(hk, g, dh), kw,
                     self.precision) * scale
@@ -303,8 +362,8 @@ class Reference:
         # charge-domain accumulation of the CAM softmax
         z = approx * scale
         e = jnp.exp(z - jnp.max(z, -1, keepdims=True)) * valid[:, None, :]
-        acc = acc + jnp.sum(e / jnp.maximum(jnp.sum(e, -1, keepdims=True),
-                                            1e-30), 1)
+        acc = c.acc + jnp.sum(e / jnp.maximum(jnp.sum(e, -1, keepdims=True),
+                                              1e-30), 1)
         return o.reshape(hk * g, dh), acc
 
     # -- one request ------------------------------------------------------------

@@ -57,7 +57,9 @@ def metric_reader(name: str, bench: Path = BENCH) -> Callable:
 
 def cell(name: str, root: Path = ROOT) -> Dict[str, Any]:
     """The cell's entry in BENCHMARK.json joined with its files: the
-    workload and config specs, and the metric entries it reports."""
+    workload and config specs, and the metric entries it reports. A cell
+    whose longest prompt plus longest output would pass its
+    configuration's `max_position_embeddings` is refused."""
     bj = benchmark(root)
     entry = next((w for w in bj["workloads"] if w["name"] == name), None)
     if entry is None:
@@ -65,6 +67,15 @@ def cell(name: str, root: Path = ROOT) -> Dict[str, Any]:
     bench = root / "bench"
     wl = workload(entry["traffic"], bench)
     cfg = config(entry["config"], bench)
+    ctx = cfg.get("model", {}).get("max_position_embeddings")
+    if ctx is not None and wl.get("classes"):
+        longest = (max(int(c["prompt"]["max"]) for c in wl["classes"])
+                   + max(int(c["output"]["max"]) for c in wl["classes"]))
+        if longest > ctx:
+            raise ValueError(
+                f"cell {name!r}: the longest prompt plus the longest output "
+                f"({longest}) exceed {entry['config']}'s "
+                f"max_position_embeddings ({ctx})")
 
     def here(m):
         return name in m.get("workloads", [name])

@@ -184,7 +184,7 @@ def test_reference_matches_program_in_f32():
     from repro.configs.base import PruneConfig
     from repro.models.transformer import Model
     for model in (MODEL, dict(MODEL, **GRANITE),
-                  dict(MODEL, hidden_act="gelu", norm="layernorm",
+                  dict(MODEL, hidden_act="gelu_pytorch_tanh", norm="layernorm",
                        tie_word_embeddings=False, attention_bias=True)):
         prune = dict(PRUNE, fused=False)
         base = harness.program_model({"name": "t", "model": model,

@@ -50,6 +50,30 @@ def test_cell_sees_only_its_metrics(tmp_path):
     assert a["per_layer"] == [] and [m["name"] for m in b["per_layer"]] == ["z"]
 
 
+@pytest.mark.parametrize("out_max,fits", [(20, True), (21, False)])
+def test_cell_fits_the_context(tmp_path, out_max, fits):
+    """A cell whose longest prompt plus longest output passes its
+    configuration's max_position_embeddings is refused."""
+    (tmp_path / "bench" / "configs").mkdir(parents=True)
+    (tmp_path / "bench" / "workloads").mkdir()
+    (tmp_path / "bench" / "configs" / "c.json").write_text(json.dumps(
+        {"name": "c", "model": {"max_position_embeddings": 100}}))
+    length = {"dist": "uniform", "min": 10}
+    (tmp_path / "bench" / "workloads" / "w.json").write_text(json.dumps(
+        {"name": "w", "config": "c", "classes": [
+            {"prompt": dict(length, max=80), "output": dict(length, max=5)},
+            {"prompt": dict(length, max=40),
+             "output": dict(length, max=out_max)}]}))
+    (tmp_path / "BENCHMARK.json").write_text(json.dumps({
+        "workloads": [{"name": "w", "config": "c", "traffic": "w"}],
+        "end_to_end": [], "per_layer": []}))
+    if fits:
+        assert spec.cell("w", tmp_path)["workload"]["name"] == "w"
+    else:
+        with pytest.raises(ValueError, match="max_position_embeddings"):
+            spec.cell("w", tmp_path)
+
+
 def test_discovery_follows_the_file_name(tmp_path):
     (tmp_path / "configs").mkdir()
     (tmp_path / "metrics").mkdir()
